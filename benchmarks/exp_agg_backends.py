@@ -4,7 +4,8 @@ Times every registered rule on each backend it declares and writes
 ``results/benchmarks/agg_backends.json``. Off-TPU the Pallas kernels run in
 interpret mode — correct but slow, so those timings measure the *fallback*,
 not the kernel (flagged ``interpret: true`` in the output). Run via
-``python -m benchmarks.run --only agg`` or ``make agg-bench``.
+``python -m benchmarks.run --only agg`` or ``make agg-bench``. A backend
+that fails is recorded in the JSON and then fails the run.
 """
 from __future__ import annotations
 
@@ -51,7 +52,7 @@ def run(quick: bool = True):
                 return spec(x, f, backend=_b)
             try:
                 ms = _time_call(jax.jit(call), x, iters)
-            except Exception as e:  # noqa: BLE001 - record, don't die
+            except Exception as e:  # noqa: BLE001 - recorded, raised below
                 entry["backends"][backend] = {"error": str(e)[:200]}
                 continue
             entry["backends"][backend] = {
@@ -63,6 +64,12 @@ def run(quick: bool = True):
     with open(OUT_PATH, "w") as fh:
         json.dump(results, fh, indent=1, default=float)
     results["out"] = OUT_PATH
+    errors = [f"{name}/{backend}: {r['error']}"
+              for name, entry in results["rules"].items()
+              for backend, r in entry["backends"].items() if "error" in r]
+    if errors:
+        raise RuntimeError(f"{len(errors)} backend(s) failed (all recorded "
+                           f"in {OUT_PATH}):\n  " + "\n  ".join(errors))
     return results
 
 

@@ -112,6 +112,8 @@ def main():
                     "(default 0.25)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.list:
         print(list_everything())
         return
@@ -145,16 +147,15 @@ def main():
         with open(os.path.join(args.out, f"{name}.json"), "w") as f:
             json.dump(res, f, indent=1, default=float)
 
-    # roofline table (if the dry-run has been run)
-    try:
-        from repro.launch import roofline
-        rows = roofline.full_table()
-        ok_rows = [r for r in rows if "skipped" not in r]
-        if ok_rows:
-            print("[roofline] single-pod baseline (naive engine):")
-            print(roofline.format_table(rows))
-    except Exception as e:  # noqa: BLE001
-        print(f"[roofline] unavailable: {e}")
+    # roofline table from the dry-run artifacts; cells the dry-run has not
+    # written come back as "missing" rows, any other failure surfaces
+    from repro.launch import roofline
+    rows = roofline.full_table()
+    if any("skipped" not in r for r in rows):
+        print("[roofline] single-pod baseline (naive engine):")
+        print(roofline.format_table(rows))
+    else:
+        print(f"[roofline] no dry-run artifacts under {roofline.RESULTS_DIR}")
     print(f"\ntotal {time.time()-t00:.1f}s")
 
     if baseline is not None:

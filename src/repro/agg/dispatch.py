@@ -36,6 +36,9 @@ _VALID = ("auto", "jnp", "pallas")
 # cwise_median kernel is sized for replica stacks (sorting network in regs)
 _MEDIAN_KERNEL_MAX_N = 64
 
+# primitive -> the backends it resolved to, recorded at trace time
+_RESOLVED: dict[str, set[str]] = {}
+
 
 def default_backend() -> str:
     return os.environ.get("REPRO_AGG_BACKEND", "auto")
@@ -85,10 +88,27 @@ def resolve_backend(backend: str | None = None, *,
     return b
 
 
+def resolved_backends(reset: bool = False) -> dict[str, list[str]]:
+    """Primitive -> the backends it resolved to in the programs traced since
+    the last reset. Resolution happens at trace time, so a program served
+    from a compile cache adds nothing."""
+    out = {k: sorted(v) for k, v in sorted(_RESOLVED.items())}
+    if reset:
+        _RESOLVED.clear()
+    return out
+
+
+def _resolve(primitive: str, backend: str | None, *,
+             pallas_ok: bool = True) -> str:
+    b = resolve_backend(backend, pallas_ok=pallas_ok)
+    _RESOLVED.setdefault(primitive, set()).add(b)
+    return b
+
+
 def pairwise_sqdists(x: jax.Array, *, backend: str | None = None,
                      interpret: bool | None = None) -> jax.Array:
     """[n, d] -> [n, n] exact squared L2 distances."""
-    if resolve_backend(backend) == "pallas":
+    if _resolve("pairwise_sqdist", backend) == "pallas":
         from ..kernels.pairwise_sqdist import ops
         return ops.pairwise_sqdists(x, interpret=interpret)
     return rules.pairwise_sqdists(x)
@@ -98,7 +118,7 @@ def subset_diameters(d2: jax.Array, masks: jax.Array, *,
                      backend: str | None = None,
                      interpret: bool | None = None) -> jax.Array:
     """[n,n] distances + [S,n] subset masks -> [S] subset diameters."""
-    if resolve_backend(backend) == "pallas":
+    if _resolve("mda_diameter", backend) == "pallas":
         from ..kernels.mda_diameter import ops
         return ops.subset_diameters(d2, masks.astype(bool),
                                     interpret=interpret)
@@ -110,7 +130,7 @@ def cwise_median(x: jax.Array, *, backend: str | None = None,
     """[n, ...] -> [...] coordinate-wise median (kernel path needs a 2D
     stack; multi-dim leaves — e.g. pytree weight matrices — fall back)."""
     ok = x.ndim == 2 and x.shape[0] <= _MEDIAN_KERNEL_MAX_N
-    if resolve_backend(backend, pallas_ok=ok) == "pallas":
+    if _resolve("cwise_median", backend, pallas_ok=ok) == "pallas":
         from ..kernels.cwise_median import ops
         return ops.cwise_median(x, interpret=interpret)
     return rules.coordinate_median(x)
@@ -134,7 +154,7 @@ def _cwise_rule(x: jax.Array, f: int, kernel_name: str, ref_fn,
     leaves and stacks beyond the kernel's n limit fall back to the jnp
     reference."""
     ok = x.ndim == 2 and x.shape[0] <= _MEDIAN_KERNEL_MAX_N
-    if resolve_backend(backend, pallas_ok=ok) == "pallas":
+    if _resolve(kernel_name, backend, pallas_ok=ok) == "pallas":
         from ..kernels.cwise_median import ops
         out = getattr(ops, kernel_name)(x, f, interpret=interpret)
         return out.astype(x.dtype)

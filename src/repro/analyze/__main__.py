@@ -4,9 +4,10 @@
 Layer 1 (AST lint + repo invariants) always runs and never imports the
 checked code. ``--hlo`` adds layer 2: before jax is imported the CLI
 forces ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (unless the
-caller already set XLA_FLAGS) so the protocol mesh audits run genuinely
-multi-device on CPU. Exit status 1 iff any finding is neither inline-
-suppressed nor in the committed baseline — the ``make lint`` contract.
+caller already set XLA_FLAGS) and ``JAX_PLATFORMS=cpu``, so the protocol
+mesh audits run genuinely multi-device on CPU, even on a machine with a TPU.
+Exit status 1 iff any finding is neither inline-suppressed nor in the
+committed baseline — the ``make lint`` contract.
 """
 from __future__ import annotations
 
@@ -43,9 +44,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.hlo:
-        # must precede any jax import anywhere in the process
+        # must precede any jax import anywhere in the process. The audit is
+        # a CPU-topology audit by design: pinning the platform also keeps
+        # this process off an accelerator that another process holds.
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     from . import findings as F
     from . import registry

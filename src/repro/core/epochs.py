@@ -93,6 +93,16 @@ def clear_epoch_cache() -> None:
     _EPOCH_CACHE.clear()
 
 
+def _arg_type(x):
+    """An argument as jit sees it: shape, dtype, weak type and, when
+    committed, placement."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, weak_type=x.aval.weak_type,
+        sharding=x.sharding if x.committed else None)
+
+
 class EpochRunner:
     """Scan/donation epoch scaffolding shared by the engines.
 
@@ -150,7 +160,19 @@ class EpochRunner:
             # of benchmark output without touching the global filter state
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return self._epoch(state, batches, *self._extra_args())
+            args = (state, batches, *self._extra_args())
+            self._dispatched = (jax.sharding.get_mesh(),
+                                jax.tree.map(_arg_type, args))
+            return self._epoch(*args)
+
+    def lower(self):
+        """The program of the last dispatched epoch, lowered again from the
+        argument types and ambient mesh it ran with: ``.compile()`` it for
+        its text or memory analysis (a persistent compile cache serves that
+        compile from the run's own entry)."""
+        mesh, args = self._dispatched
+        with jax.set_mesh(mesh):
+            return self._epoch.lower(*args)
 
     # -- full-run API --------------------------------------------------------
     def run(self, state, batches=None, *, stream=None,

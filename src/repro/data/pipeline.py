@@ -146,6 +146,13 @@ class DeviceBatchStream:
         if length:
             self._key = _advance_key(self._key, length)
 
+    def place(self, sharding):
+        """Commit the carried key to ``sharding``. A draw under one ambient
+        mesh commits the key to that mesh's devices; a stream that outlives
+        its mesh (the elastic runner re-forms the fleet) is moved onto the
+        next one before drawing under it."""
+        self._key = jax.device_put(self._key, sharding)
+
     def eval_set(self, n: int = 2048, eval_seed: int = 10_007):
         """Held-out eval set, identical to ``classification_stream``'s."""
         x, y = sample_classification_batch(jax.random.PRNGKey(eval_seed),

@@ -223,8 +223,12 @@ def models_table() -> str:
             cfg = get_bundle(m["arch"],
                              reduced=m.get("reduced", False)).cfg
             defn = f"zoo `{m['arch']}`"
+            cuts = ", ".join(f"{k} {v}" for k, v in m.items()
+                             if k not in ("arch", "reduced"))
             if m.get("reduced"):
                 defn += " (reduced)"
+            elif cuts:
+                defn += f" (published widths; cut: {cuts})"
             fam, runners = cfg.family, "`protocol`"
             metric = "negative eval loss (higher is better)"
         else:

@@ -36,6 +36,7 @@ from typing import Any
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..agg import default_backend
 from ..agg.dispatch import backend_override
@@ -73,9 +74,10 @@ class RunResult:
     """Uniform result of :func:`run` across the three runners.
 
     ``logs``/``final``/``wall_s``/``provenance``/``netsim`` serialize via
-    :meth:`to_dict`; ``state`` (the final ``SimState``) and ``buffers`` (the
-    dense per-step device metric buffers, host numpy) are runtime attachments
-    for tests and notebook analysis, never written to JSON.
+    :meth:`to_dict`; ``state`` (the final ``SimState``), ``buffers`` (the
+    dense per-step device metric buffers, host numpy) and ``engine`` (the
+    protocol runner's epoch engine, for lowering its program) are runtime
+    attachments for tests and notebook analysis, never written to JSON.
     """
     experiment: Experiment
     logs: list[dict]
@@ -85,6 +87,7 @@ class RunResult:
     netsim: dict | None = None
     state: Any = field(default=None, repr=False, compare=False)
     buffers: dict | None = field(default=None, repr=False, compare=False)
+    engine: Any = field(default=None, repr=False, compare=False)
 
     def to_dict(self) -> dict[str, Any]:
         out = {"experiment": self.experiment.to_dict(),
@@ -302,6 +305,9 @@ def _run_protocol(e: Experiment, delivery=None, netsim=None) -> RunResult:
             state, mbuf = eng.run(state, stream=stream, steps=e.steps,
                                   epoch_steps=e.epoch_steps)
         wall = time.time() - t0
+        # under the run's mesh: the state is committed to its devices, and
+        # a kernel on them runs per shard only where the mesh is ambient
+        final = _final_metrics(e, state, acc, (ex, ey), mbuf)
 
     logs = []
     for i in range(0, e.steps, e.metrics_every):
@@ -313,13 +319,12 @@ def _run_protocol(e: Experiment, delivery=None, netsim=None) -> RunResult:
         if stal:
             m.update(stal)
         logs.append(m)
-    final = _final_metrics(e, state, acc, (ex, ey), mbuf)
     prov = provenance(e.spec_hash)
     prov["mesh"] = dict(zip(mesh.axis_names,
                             (int(n) for n in mesh.devices.shape)))
     prov["protocol_engine"] = pcfg.engine
     return RunResult(e, logs, final, wall, prov, netsim=netsim, state=state,
-                     buffers=mbuf)
+                     buffers=mbuf, engine=eng)
 
 
 class _GroupView:
@@ -424,6 +429,29 @@ def _run_elastic(e: Experiment) -> RunResult:
             continue  # fully replayed by the checkpoint (keep the last seg)
         pcfg = _membership.epoch_config(pcfg0, seg.active, synchronous=sync)
         mesh = _protocol_mesh(pcfg.n_groups)
+        if state is not None and prev_active != seg.active:
+            # re-form on the old mesh's layout, before the new mesh becomes
+            # ambient (a jitted op on arrays committed to the old devices
+            # is refused under another mesh), then place onto the new one
+            params = _membership.reform_params(state.params, prev_active,
+                                               seg.active)
+            # re-stack per-replica optimizer moments alongside the params
+            # (scalars — adamw's step count — ride through untouched)
+            opt = jax.tree.map(
+                lambda l: _membership.reform_params(l, prev_active,
+                                                    seg.active)
+                if getattr(l, "ndim", 0) >= 1
+                and l.shape[0] == len(prev_active) else l,
+                state.opt)
+            state = _protocol.ByzState(params=params, t=state.t,
+                                       key=state.key, opt=opt)
+            state = jax.tree.map(jax.device_put, state,
+                                 _shardings(pcfg, mesh))
+            if e.ckpt_dir:
+                # the boundary save overwrites the chunk save at the same
+                # step: the post-re-form state (new G) is what a resume
+                # of THIS epoch must restore
+                _save(seg.start, state, seg.active)
         with use_mesh(mesh):
             eng = _protocol.ProtocolEngine(
                 bundle, pcfg, e.build_schedule(), mesh=mesh,
@@ -445,28 +473,9 @@ def _run_elastic(e: Experiment) -> RunResult:
                     stream.skip(start)
                 else:
                     state = eng.init_state(jax.random.PRNGKey(e.seed))
-            elif prev_active != seg.active:
-                params = _membership.reform_params(state.params, prev_active,
-                                                   seg.active)
-                # re-stack per-replica optimizer moments alongside the params
-                # (scalars — adamw's step count — ride through untouched)
-                opt = jax.tree.map(
-                    lambda l: _membership.reform_params(l, prev_active,
-                                                        seg.active)
-                    if getattr(l, "ndim", 0) >= 1
-                    and l.shape[0] == len(prev_active) else l,
-                    state.opt)
-                state = _protocol.ByzState(params=params, t=state.t,
-                                           key=state.key, opt=opt)
-                state = jax.tree.map(jax.device_put, state,
-                                     _shardings(pcfg, mesh))
-                if e.ckpt_dir:
-                    # the boundary save overwrites the chunk save at the same
-                    # step: the post-re-form state (new G) is what a resume
-                    # of THIS epoch must restore
-                    _save(seg.start, state, seg.active)
             prev_active = seg.active
 
+            stream.place(NamedSharding(mesh, PartitionSpec()))
             seg_stream = _GroupView(stream, pcfg.n_groups)
             done = max(seg.start, start)
             while done < seg.stop:

@@ -66,6 +66,13 @@ MODELS: dict[str, dict[str, Any]] = {
     "tfm_tiny": {"arch": "phi4-mini-3.8b", "reduced": True},
     "moe_tiny": {"arch": "qwen3-moe-235b-a22b", "reduced": True},
     "rwkv_tiny": {"arch": "rwkv6-3b", "reduced": True},
+    # phi4-mini-3.8b at its published widths (d_model 3072, 24/8 heads of
+    # 128, d_ff 8192, attention blocks q 512 / kv 1024), sized so that G=4
+    # f32 replicas train on one 16 GB chip. The override keys are the cuts:
+    # n_layers 32 -> 2 and a vocabulary slice 200064 -> 16384 (≈0.25B
+    # params, ≈1 GB f32 per replica). chip_smoke.py drives it.
+    "phi4_mini_cut": {"arch": "phi4-mini-3.8b", "reduced": False,
+                      "n_layers": 2, "vocab": 16384},
 }
 
 
@@ -86,6 +93,8 @@ DATA: dict[str, MixtureSpec | TokenSpec] = {
     "mixture5_small": MixtureSpec(n_classes=5, dim=16, sep=2.5),
     # LM token task matching the reduced zoo vocab (ArchConfig.reduced)
     "tokens_tiny": TokenSpec(vocab=512, seq=64),
+    # LM token task for phi4_mini_cut: its vocabulary slice, 2048-token rows
+    "tokens_v16k_s2k": TokenSpec(vocab=16384, seq=2048),
 }
 
 #: lr-schedule registry: name -> factory(lr0, decay) (paper condition B.1)

@@ -23,6 +23,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+# minor dim of the per-row statistics (m, l, lse, delta): one full lane
+# register, so every block meets the TPU's (8, 128) tiling
+LANES = 128
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -55,33 +58,36 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             mask &= kpos > (qpos + off - window)
     s = jnp.where(mask, s, NEG)
 
-    m_prev = m_scr[...]                                # [qb]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    # m/l are lane-replicated [qb, 128] (the TPU tiling refuses 1-D VMEM
+    # vectors); column 0 is read back as a [qb, 1] broadcast operand
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = (acc_scr[...] * corr[:, :1]
                     + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                           preferred_element_type=jnp.float32))
     m_scr[...] = m_new
 
     @pl.when(ki == n_kv - 1)
     def _finish():
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l_scr[...], 1e-30)[:, None]).astype(o_ref.dtype)
-        # log-sum-exp of the (scaled, masked) scores per q row — the backward
-        # kernels re-derive p = exp(s - lse) from it without re-running the
-        # online softmax. Fully-masked (padded) rows get lse ~ NEG; their
-        # upstream do is zero-padded, so their garbage p never contributes.
-        lse_scr = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
-        lse_ref[0] = lse_scr
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
+        # log-sum-exp of the (scaled, masked) scores per q row, stored
+        # lane-dense like m/l — the backward kernels re-derive
+        # p = exp(s - lse) from it without re-running the online softmax.
+        # Fully-masked (padded) rows get lse ~ NEG; their upstream do is
+        # zero-padded, so their garbage p never contributes.
+        lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
 def flash_pallas_call(bh: int, sq_pad: int, skv_pad: int, hd_pad: int, *,
                       sq: int, skv: int, causal: bool, window: int,
                       q_block: int, kv_block: int, scale: float, dtype,
                       interpret: bool = False):
-    """Forward: (q, k, v) [bh, s_pad, hd_pad] -> (out, lse [bh, sq_pad])."""
+    """Forward: (q, k, v) [bh, s_pad, hd_pad] -> (out, lse [bh, sq_pad, 128]);
+    lse is replicated over its 128 lanes."""
     n_q = sq_pad // q_block
     n_kv = skv_pad // kv_block
     kern = partial(_flash_kernel, causal=causal, window=window, sq=sq,
@@ -97,15 +103,15 @@ def flash_pallas_call(bh: int, sq_pad: int, skv_pad: int, hd_pad: int, *,
         ],
         out_specs=[
             pl.BlockSpec((1, q_block, hd_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, q_block, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_pad, hd_pad), dtype),
-            jax.ShapeDtypeStruct((bh, sq_pad), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq_pad, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((q_block,), jnp.float32),
-            pltpu.VMEM((q_block,), jnp.float32),
+            pltpu.VMEM((q_block, LANES), jnp.float32),
+            pltpu.VMEM((q_block, LANES), jnp.float32),
             pltpu.VMEM((q_block, hd_pad), jnp.float32),
         ],
         interpret=interpret,
@@ -115,7 +121,7 @@ def flash_pallas_call(bh: int, sq_pad: int, skv_pad: int, hd_pad: int, *,
 def _bwd_mask_and_p(q, k, lse, qi, ki, *, causal, window, sq, skv,
                     q_block, kv_block, scale):
     """Recompute the [qb, kb] probability tile exactly as the forward masked
-    it (padding + causal + window), from the saved per-row lse."""
+    it (padding + causal + window), from the saved per-row lse [qb, 1]."""
     s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     qpos = qi * q_block + jax.lax.broadcasted_iota(jnp.int32,
@@ -129,7 +135,7 @@ def _bwd_mask_and_p(q, k, lse, qi, ki, *, causal, window, sq, skv,
         if window > 0:
             mask &= kpos > (qpos + off - window)
     s = jnp.where(mask, s, NEG)
-    return jnp.exp(s - lse[:, None])
+    return jnp.exp(s - lse)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -147,12 +153,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    p = _bwd_mask_and_p(q, k, lse_ref[0], qi, ki, causal=causal,
+    p = _bwd_mask_and_p(q, k, lse_ref[0][:, :1], qi, ki, causal=causal,
                         window=window, sq=sq, skv=skv, q_block=q_block,
                         kv_block=kv_block, scale=scale)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [qb, kb]
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0][:, :1])
     dq_scr[...] += scale * jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -177,14 +183,14 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    p = _bwd_mask_and_p(q, k, lse_ref[0], qi, ki, causal=causal,
+    p = _bwd_mask_and_p(q, k, lse_ref[0][:, :1], qi, ki, causal=causal,
                         window=window, sq=sq, skv=skv, q_block=q_block,
                         kv_block=kv_block, scale=scale)
     dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0][:, :1])
     dk_scr[...] += scale * jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -212,8 +218,8 @@ def flash_bwd_dq_call(bh: int, sq_pad: int, skv_pad: int, hd_pad: int, *,
             pl.BlockSpec((1, kv_block, hd_pad), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, kv_block, hd_pad), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, q_block, hd_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, q_block, LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, q_block, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, q_block, hd_pad), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_pad, hd_pad), dtype),
@@ -240,8 +246,8 @@ def flash_bwd_dkv_call(bh: int, sq_pad: int, skv_pad: int, hd_pad: int, *,
         in_specs=[
             pl.BlockSpec((1, q_block, hd_pad), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, q_block, hd_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, q_block, LANES), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, q_block, LANES), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, kv_block, hd_pad), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, kv_block, hd_pad), lambda b, i, j: (b, i, 0)),
         ],

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import flash_bwd_dkv_call, flash_bwd_dq_call, flash_pallas_call
+from .kernel import (LANES, flash_bwd_dkv_call, flash_bwd_dq_call,
+                     flash_pallas_call)
 
 _LANE = 128
 
@@ -78,7 +79,8 @@ def _flash_fwd(q, k, v, causal, window, q_block, kv_block, interpret):
         window=window, q_block=q_block, kv_block=kv_block, scale=scale,
         dtype=q.dtype, interpret=interpret)(qb, kb, vb)
     out = _from_bh(ob, B, H, Sq, hd)
-    return out, (q, k, v, ob, lse)
+    # keep one lane of the lane-replicated lse as the residual
+    return out, (q, k, v, ob, lse[..., 0])
 
 
 def _flash_bwd(causal, window, q_block, kv_block, interpret, res, do):
@@ -96,6 +98,9 @@ def _flash_bwd(causal, window, q_block, kv_block, interpret, res, do):
     # delta_i = sum_d do_id * o_id (zero on padded rows since do is
     # zero-padded) — plain JAX, one [bh, sq_pad] vector
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32), axis=-1)
+    # the kernels read both per-row statistics lane-replicated
+    lse, delta = (jnp.broadcast_to(x[..., None], x.shape + (LANES,))
+                  for x in (lse, delta))
 
     common = dict(sq=Sq, skv=Skv, causal=causal, window=window,
                   q_block=q_block, kv_block=kv_block, scale=scale,
@@ -124,7 +129,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret: bool | None = None):
     """Fused attention, forward AND backward. q: [B, Sq, H, hd]; k, v:
     [B, Skv, kvH, hd] (GQA: kv heads repeated into H, gradients summed back).
-    Returns [B, Sq, H, hd]."""
+    Returns [B, Sq, H, hd].
+
+    A per-device op: XLA cannot partition a Mosaic kernel, so a caller under
+    a multi-device mesh runs it per shard inside a ``shard_map``."""
     if interpret is None:
         interpret = _default_interpret()
     return _flash(q, k, v, causal, window, q_block, kv_block, interpret)

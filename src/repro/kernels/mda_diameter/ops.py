@@ -37,8 +37,8 @@ def subset_diameters(d2: jax.Array, masks: jax.Array, *, block_s: int = 512,
     d2p = jnp.zeros((n_pad, n_pad), jnp.float32).at[:n, :n].set(d2)
     mp = jnp.zeros((s_pad, n_pad), jnp.float32).at[:s, :n].set(
         masks.astype(jnp.float32))
-    out = diam_pallas_call(n_pad, s_pad, block_s, interpret)(d2p, mp)
-    return out[0, :s]
+    out = diam_pallas_call(n, n_pad, s_pad, block_s, interpret)(d2p, mp)
+    return out[:s, 0]
 
 
 def mda(x: jax.Array, f: int, *, interpret: bool | None = None) -> jax.Array:

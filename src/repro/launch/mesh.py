@@ -19,44 +19,26 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-# --- JAX version compat -----------------------------------------------------
-# Newer JAX exposes jax.sharding.AxisType / jax.make_mesh(axis_types=...) /
-# jax.set_mesh; the pinned 0.4.x has none of these. All mesh construction and
-# ambient-mesh scoping must go through the helpers below so the rest of the
-# codebase stays version-agnostic.
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
+#: every mesh axis is Auto: shardings propagate through GSPMD
+_AUTO = jax.sharding.AxisType.Auto
+
+#: context manager installing a mesh as the ambient mesh
+use_mesh = jax.set_mesh
 
 
-def _auto(n):
-    return (jax.sharding.AxisType.Auto,) * n if _HAS_AXIS_TYPE else None
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis_types where supported."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the available devices, all axes Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def _mk_mesh(devs, names):
-    if _HAS_AXIS_TYPE:
-        return Mesh(devs, names, axis_types=_auto(len(names)))
-    return Mesh(devs, names)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh: jax.set_mesh
-    on new JAX, the Mesh resource-env context on 0.4.x (Mesh is its own
-    context manager there)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return Mesh(devs, names, axis_types=(_AUTO,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_size(mesh) -> int:

@@ -1,12 +1,12 @@
 """Roofline analysis from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI. cost_analysis() values are PER-DEVICE (verified
-empirically), so:
+Hardware model: the per-chip peaks of :data:`PEAKS`, keyed by the device kind
+JAX reports; the dry-run models a TPU v5e pod (:data:`DRYRUN_DEVICE_KIND`).
+cost_analysis() values are PER-DEVICE (verified empirically), so:
 
-    compute term    = HLO_FLOPs_per_device / 197e12              [s]
-    memory term     = HLO_bytes_per_device / 819e9               [s]
-    collective term = ring-model collective bytes per device / 50e9 [s]
+    compute term    = HLO_FLOPs_per_device / peak bf16 FLOP/s          [s]
+    memory term     = HLO_bytes_per_device / peak HBM bytes/s          [s]
+    collective term = ring-model collective bytes per device / link B/s [s]
 
 MODEL_FLOPS = 6*N*D (dense) or 6*N_active*D (MoE) per step across the whole
 job; the ratio MODEL_FLOPS / (HLO_FLOPs * chips) exposes remat/dispatch/
@@ -20,9 +20,24 @@ import os
 from ..configs.shapes import SHAPES
 from ..models.registry import ARCH_IDS, get_bundle
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+#: published per-chip peaks, keyed by ``jax.Device.device_kind``. TPU v5e:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+#: 1,600 Gbit/s of chip-to-chip interconnect (50 GB/s on each of 4 links).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+#: the chip the dry-run's 16x16 production mesh models
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; have {sorted(PEAKS)}") from None
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -74,18 +89,19 @@ def roofline_row(arch: str, shape: str, mesh: str = "16x16",
     if res is None or "skipped" in res or "error" in res:
         return {"arch": arch, "shape": shape,
                 "skipped": res.get("skipped") if res else "missing"}
+    pk = peaks(DRYRUN_DEVICE_KIND)
     ex = res["extrapolated"]
     chips = res["n_devices"]
-    t_comp = ex["flops"] / PEAK_FLOPS
-    t_mem = ex["bytes_accessed"] / HBM_BW
-    t_coll = ex["collective_bytes_per_device"] / LINK_BW
+    t_comp = ex["flops"] / pk["flops"]
+    t_mem = ex["bytes_accessed"] / pk["hbm_bw"]
+    t_coll = ex["collective_bytes_per_device"] / pk["link_bw"]
     # amortised DMC gather
     g = res.get("gather")
     T = 50
     if g:
-        t_comp += g["flops"] / PEAK_FLOPS / T
-        t_mem += g["bytes_accessed"] / HBM_BW / T
-        t_coll += g["collective_bytes_per_device"] / LINK_BW / T
+        t_comp += g["flops"] / pk["flops"] / T
+        t_mem += g["bytes_accessed"] / pk["hbm_bw"] / T
+        t_coll += g["collective_bytes_per_device"] / pk["link_bw"] / T
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     dom = max(terms, key=terms.get)
     bound = max(terms.values())
@@ -94,7 +110,7 @@ def roofline_row(arch: str, shape: str, mesh: str = "16x16",
     useful = mf / hlo_total if hlo_total else 0.0
     # roofline fraction: useful model flops per second at the bound, vs peak
     step_time = bound
-    mfu = mf / (step_time * chips * PEAK_FLOPS) if step_time > 0 else 0.0
+    mfu = mf / (step_time * chips * pk["flops"]) if step_time > 0 else 0.0
     mem = res["full"]["memory"]
     per_dev_gib = (mem["argument_bytes"] + mem["temp_bytes"]
                    + mem["output_bytes"] - mem["alias_bytes"]) / 2**30

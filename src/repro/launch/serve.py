@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from ..models import sharding as shrules
 from ..models.registry import get_bundle
-from .mesh import compat_make_mesh, make_serve_mesh, use_mesh
+from .mesh import make_mesh, make_serve_mesh, use_mesh
 from .steps import serve_rules
 
 
@@ -70,7 +70,7 @@ def main(argv=None):
         d, m = (int(x) for x in args.mesh.split("x"))
     else:
         d, m = n_dev, 1
-    base = compat_make_mesh((d, m), ("data", "model"))
+    base = make_mesh((d, m), ("data", "model"))
     smesh = make_serve_mesh(base)
 
     bundle = get_bundle(args.arch, reduced=args.reduced)

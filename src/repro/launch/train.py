@@ -29,7 +29,7 @@ from ..data.pipeline import token_stream
 from ..models import sharding as shrules
 from ..models.registry import get_bundle
 from ..optim.schedules import inverse_linear
-from .mesh import compat_make_mesh, make_byz_mesh, use_mesh
+from .mesh import make_mesh, make_byz_mesh, use_mesh
 from .steps import train_rules
 
 
@@ -59,7 +59,7 @@ def main(argv=None):
     else:
         m = 1
         d = n_dev
-    base = compat_make_mesh((d, m), ("data", "model"))
+    base = make_mesh((d, m), ("data", "model"))
     G = args.groups or d
     bmesh = make_byz_mesh(base, G)
 

@@ -118,6 +118,50 @@ def _naive_attention(q, k, v, *, causal, window, cross):
     return out.astype(q.dtype)
 
 
+def _flash_attention(q, k, v, *, causal, window, q_block, kv_block):
+    """The Pallas flash kernels under the ambient mesh. XLA cannot partition
+    a Mosaic kernel, so on a multi-device mesh they run per shard inside a
+    ``shard_map`` laid out by the installed ``act_heads``/``act_kv_heads``
+    rules (replicated where none is installed; kv rows follow q's batch
+    split). Where q's heads are split and kv's are whole, each shard takes
+    the kv head of each of its q heads."""
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from ..kernels.flash_attention.ops import flash_attention
+    from .sharding import rule
+    fa = partial(flash_attention, causal=causal, window=window,
+                 q_block=q_block, kv_block=kv_block)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fa(q, k, v)
+
+    def spec4(name):
+        r = rule(name)
+        spec = tuple(r.spec) if r is not None else ()
+        return spec + (None,) * (4 - len(spec))
+
+    batch, _, q_heads, _ = spec4("act_heads")
+    kv_heads = spec4("act_kv_heads")[2]
+    if kv_heads is not None and kv_heads != q_heads:
+        raise ValueError(f"kv heads split over {kv_heads!r} but q heads over "
+                         f"{q_heads!r}: the kernel needs q's split or none")
+    rep = q.shape[2] // k.shape[2]
+
+    def attend(q, k, v):
+        if q_heads is not None and kv_heads is None:
+            h = q.shape[2]
+            idx = (jax.lax.axis_index(q_heads) * h + jnp.arange(h)) // rep
+            k, v = jnp.take(k, idx, axis=2), jnp.take(v, idx, axis=2)
+        return fa(q, k, v)
+
+    q_spec = P(batch, None, q_heads, None)
+    kv_spec = P(batch, None, kv_heads, None)
+    return jax.shard_map(attend, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
+
+
 def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       q_block: int = 512, kv_block: int = 512,
                       cross: bool = False) -> jax.Array:
@@ -143,9 +187,9 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
         # them too). REPRO_NO_FLASH=1 falls back to the blocked path;
         # REPRO_FLASH=1 forces the kernels elsewhere (Pallas interpret mode
         # off-TPU — the CI hot-path smoke).
-        from ..kernels.flash_attention.ops import flash_attention as _fa
-        return _fa(q, k, v, causal=causal and not cross, window=window,
-                   q_block=q_block, kv_block=kv_block)
+        return _flash_attention(q, k, v, causal=causal and not cross,
+                                window=window, q_block=q_block,
+                                kv_block=kv_block)
     B, Sq, H, hd = q.shape
     Skv, kvH = k.shape[1], k.shape[2]
     rep = H // kvH

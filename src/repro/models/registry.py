@@ -132,11 +132,15 @@ class ModelBundle:
 def get_bundle(arch_id: str, reduced: bool = False, depth: int | None = None,
                **overrides) -> ModelBundle:
     """depth: override n_layers only (dry-run cost probes — everything else
-    stays full-size; encoder depth scales with it for enc-dec archs)."""
+    stays full-size; encoder depth scales with it for enc-dec archs).
+    ``overrides`` replace config fields: on the reduced sibling when
+    ``reduced``, else on the published config."""
     import dataclasses
     cfg = get_config(arch_id)
     if reduced:
         cfg = cfg.reduced(**overrides)
+    elif overrides:
+        cfg = dataclasses.replace(cfg, **overrides)
     if depth is not None:
         upd = {"n_layers": depth}
         if cfg.encoder_layers:

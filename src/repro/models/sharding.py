@@ -25,6 +25,11 @@ def sharding_rules(rules: dict):
         _RULES = old
 
 
+def rule(name: str):
+    """The installed NamedSharding for ``name``, or None."""
+    return _RULES.get(name)
+
+
 def shard(x, name: str):
     spec = _RULES.get(name)
     if spec is None:

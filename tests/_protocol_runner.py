@@ -10,14 +10,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import protocol  # noqa: E402
 from repro.core.attacks import ByzantineSpec  # noqa: E402
-from repro.launch.mesh import (compat_make_mesh, make_byz_mesh,  # noqa: E402
+from repro.launch.mesh import (make_mesh, make_byz_mesh,  # noqa: E402
                                use_mesh)
 from repro.models.registry import get_bundle  # noqa: E402
 from repro.optim.schedules import inverse_linear  # noqa: E402
 
 
 def main():
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     bmesh = make_byz_mesh(mesh, n_groups=4)
     bundle = get_bundle("phi4-mini-3.8b", reduced=True)
 

@@ -14,7 +14,7 @@ import numpy as np  # noqa: E402
 import repro.exp as exp  # noqa: E402
 from repro.checkpoint import checkpointer as ck  # noqa: E402
 from repro.core.attacks import ByzantineSpec  # noqa: E402
-from repro.launch.mesh import (compat_make_mesh, make_serve_mesh,  # noqa: E402
+from repro.launch.mesh import (make_mesh, make_serve_mesh,  # noqa: E402
                                use_mesh)
 from repro.launch.steps import serve_rules  # noqa: E402
 from repro.models.registry import get_bundle  # noqa: E402
@@ -44,7 +44,7 @@ def main():
 
     # 2. multi-replica transformer serving under the serve mesh's sharding
     #    rules: 1-of-4 Byzantine, continuations token-identical to honest
-    base = compat_make_mesh((4, 2), ("data", "model"))
+    base = make_mesh((4, 2), ("data", "model"))
     smesh = make_serve_mesh(base)
     bundle = get_bundle("phi4-mini-3.8b", reduced=True)
     rules = serve_rules(smesh, bundle.cfg)

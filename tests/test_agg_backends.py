@@ -153,3 +153,16 @@ def test_auto_resolution_matches_platform():
     expect = "pallas" if jax.default_backend() == "tpu" else "jnp"
     assert agg.resolve_backend("auto") == expect
     assert agg.resolve_backend(None) in ("jnp", "pallas")
+
+
+def test_resolved_backends_records_each_primitive():
+    """What each primitive resolved to while tracing, per primitive; a
+    multi-dim leaf the kernel cannot take records jnp beside pallas."""
+    agg.dispatch.resolved_backends(reset=True)
+    jax.jit(lambda x: agg.cwise_median(x, backend="pallas",
+                                       interpret=True))(rand(5, 16))
+    jax.jit(lambda x: agg.cwise_median(x))(rand(5, 4, 4))
+    agg.pairwise_sqdists(rand(5, 8), backend="jnp")
+    assert agg.dispatch.resolved_backends(reset=True) == {
+        "cwise_median": ["jnp", "pallas"], "pairwise_sqdist": ["jnp"]}
+    assert agg.dispatch.resolved_backends() == {}

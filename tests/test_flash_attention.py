@@ -110,3 +110,21 @@ def test_flash_grad_dtypes():
                                         kv_block=16),
         q, k, v, jnp.ones((B, S, H, hd), jnp.bfloat16))
     assert dq.dtype == dk.dtype == dv.dtype == jnp.bfloat16
+
+
+def test_flash_sharded_under_mesh_rules():
+    """Under a multi-device mesh the kernels run per shard, laid out by the
+    installed activation rules; every layout matches the reference, forward
+    and backward (subprocess: 4 forced host devices)."""
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(__file__)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (os.path.abspath(os.path.join(here, "..", "src"))
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    out = subprocess.run(
+        [sys.executable, os.path.join(here, "_flash_mesh_runner.py")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "FLASH_MESH_PASS" in out.stdout, out.stdout

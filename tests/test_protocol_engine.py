@@ -10,6 +10,8 @@ looser tolerance (a max-minus-min of nearly-identical replicas amplifies the
 last-ulp noise). Mirrors ``tests/test_engine.py``'s gather off-by-one,
 chunking, and ``TraceDelivery`` (realized netsim quorums + trace wrap) cases.
 """
+import contextlib
+
 import jax
 import numpy as np
 import pytest
@@ -189,6 +191,25 @@ class TestEngineMechanics:
         b = protocol.ProtocolEngine(bundle, make_pcfg(make_cfg(), "naive"),
                                     inverse_linear(0.05, 0.01))
         assert a._epoch is not b._epoch
+
+    @pytest.mark.parametrize("on_mesh", [False, True])
+    def test_lower_reproduces_the_dispatched_program(self, on_mesh):
+        # lower() rebuilds the program of the last dispatch from its
+        # argument types and ambient mesh: the same module as lowering the
+        # real arguments, so a persistent compile cache serves its compile
+        init, loss, acc = problem()
+        mesh = make_protocol_mesh(G) if on_mesh else None
+        eng = protocol.ProtocolEngine(
+            protocol.ProblemBundle(init=init, loss=loss),
+            make_pcfg(make_cfg()), inverse_linear(0.05, 0.01), mesh=mesh,
+            acc_fn=acc, eval_set=eval_pair())
+        with use_mesh(mesh) if on_mesh else contextlib.nullcontext():
+            state = eng.init_state(jax.random.PRNGKey(0))
+            batches = DeviceBatchStream(0, MIX, G, BATCH).next(5)
+            want = eng._epoch.lower(state, batches,
+                                    *eng._extra_args()).as_text()
+            state, _ = eng.run_epoch(state, batches)
+        assert eng.lower().as_text() == want
 
     def test_acc_fn_requires_eval_set(self):
         init, loss, acc = problem()

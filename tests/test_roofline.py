@@ -63,9 +63,10 @@ def test_roofline_row_and_table_from_artifact(tmp_path, monkeypatch):
     monkeypatch.setattr(roofline, "RESULTS_DIR", str(tmp_path))
 
     row = roofline.roofline_row("whisper-small", "train_4k")
+    hbm_bw = roofline.PEAKS["TPU v5 lite"]["hbm_bw"]
     assert row["dominant"] == "memory"
     assert row["t_memory_s"] == pytest.approx(
-        1.0e12 / roofline.HBM_BW + 1.0e10 / roofline.HBM_BW / 50)
+        1.0e12 / hbm_bw + 1.0e10 / hbm_bw / 50)
     assert row["est_step_s"] == pytest.approx(row["t_memory_s"])
     assert 0 < row["roofline_fraction"] < 1
     assert row["mem_per_dev_gib"] == pytest.approx(10.0)
@@ -77,6 +78,11 @@ def test_roofline_row_and_table_from_artifact(tmp_path, monkeypatch):
     table = roofline.format_table([row, skip])
     assert "whisper-small" in table and "SKIP" in table
     assert "memory" in table
+
+    # peaks are looked up by device kind; a chip without published peaks
+    # is an error, never a v5e default
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks("TPU v4")
 
 
 def test_load_cell_missing_is_none(tmp_path, monkeypatch):
