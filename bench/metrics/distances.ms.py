@@ -1,0 +1,12 @@
+"""distances.ms (ms): device time per training step of the ops the program
+built in its ``distances`` stage: the [G, G] gradient distances over all
+parameters (``tree_gram``, ``sqdists_from_gram``) and MDA's choice of subset
+(``quorum_weights``). Summed over the traced window's ops (clipped to it),
+averaged over the chips, divided by the window's steps
+(``benchlib.stages``). Layer: the ByzSGD step. Moves ``tokens_per_s``. None
+where the program names no stages or the stage ran no op."""
+from benchlib import stages
+
+
+def read(run):
+    return stages.stage_ms(run, "distances")

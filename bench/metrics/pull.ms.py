@@ -1,0 +1,12 @@
+"""pull.ms (ms): device time per training step of the ops the program built in
+its ``pull`` stage: the Median (or round-robin) pull of the G replicas, the
+model attack where one is injected, and the cast to the compute dtype.
+Summed over the traced window's ops (clipped to it), averaged over the
+chips, divided by the window's steps (``benchlib.stages``). Layer: the
+ByzSGD step. Moves ``tokens_per_s``. None where the program names no stages
+or the stage ran no op."""
+from benchlib import stages
+
+
+def read(run):
+    return stages.stage_ms(run, "pull")

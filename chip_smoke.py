@@ -11,9 +11,8 @@ Phase (b) trains phi4-mini-3.8b at its published widths (the
 through ``runner="protocol"`` with G=4 worker+server groups, T=5, for 10
 steps on 2048-token rows. Weights and data are random, made from the seed.
 
-Each phase prints its losses (or accuracies) at the first and last step, wall
-and compile seconds, the mesh and the backend each aggregation primitive
-resolved to. It fails (non-zero exit, no result line) if any check fails:
+Each phase prints its losses (or accuracies) at the first and last step, the
+mesh and the backend each aggregation primitive resolved to. It fails (non-zero exit, no result line) if any check fails:
 non-finite or non-improving metrics, no flash-attention kernel in the
 protocol epoch the run dispatched, flash forward or gradients off the float32
 reference attention by rel-L2 2e-2, or (``--chips 4``) a 4-chip run that
@@ -44,22 +43,6 @@ def _fail(msg: str) -> None:
     raise SystemExit(1)
 
 
-class _CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, from its own
-    monitoring events."""
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self, jax):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, secs, **_):
-        if event in self.EVENTS:
-            self.total += secs
-
-
 def _phase_spec(exp):
     """Phase (b): G=4 co-located groups on the dense architecture at its
     published widths, f_w=1 as in the lm presets. At d_model 3072 plain SGD
@@ -72,15 +55,14 @@ def _phase_spec(exp):
         lr0=5e-4, metrics_every=1, eval_n=4)
 
 
-def _report(tag, res, compile_s, metric, dispatch):
+def _report(tag, res, metric, dispatch):
     first, last = res.logs[0], res.final
     sign = -1.0 if metric == "loss" else 1.0
     vals = [sign * m["acc"] for m in res.logs] + [sign * last["acc"]]
     print(f"[{tag}] {res.experiment.name}: runner={res.experiment.runner} "
           f"mesh={res.provenance.get('mesh', 'single device, no mesh')} "
           f"{metric} {vals[0]:.4f} (step {first['step']}) -> {vals[-1]:.4f} "
-          f"(final, after step {res.experiment.steps}) "
-          f"wall {res.wall_s:.2f}s compile {compile_s:.2f}s")
+          f"(final, after step {res.experiment.steps})")
     print(f"[{tag}] {metric} per logged step: "
           + " ".join(f"{v:.4f}" for v in vals[:-1]))
     print(f"[{tag}] agg primitive backends: "
@@ -156,15 +138,13 @@ def _flash_check(res):
               "fell back to the jnp path")
 
 
-def one_chip(jax, exp, dispatch, clock):
-    c0 = clock.total
+def one_chip(jax, exp, dispatch):
     res = exp.run("quickstart", steps=50, metrics_every=10)
-    _report("a", res, clock.total - c0, "acc", dispatch)
+    _report("a", res, "acc", dispatch)
     del res
 
-    c0 = clock.total
     res = exp.run(_phase_spec(exp))
-    _report("b", res, clock.total - c0, "loss", dispatch)
+    _report("b", res, "loss", dispatch)
     _memory(jax, "b")
     _flash_check(res)
     del res
@@ -177,15 +157,14 @@ def _host_params(jax, state):
             for l in jax.tree.leaves(jax.device_get(state.params))]
 
 
-def four_chips(jax, exp, dispatch, clock):
+def four_chips(jax, exp, dispatch):
     import numpy as np
     from repro.exp import runners
     from repro.launch.mesh import make_protocol_mesh, use_mesh
 
     spec = _phase_spec(exp)
-    c0 = clock.total
     res = exp.run(spec)
-    _report("b4", res, clock.total - c0, "loss", dispatch)
+    _report("b4", res, "loss", dispatch)
     if res.provenance["mesh"] != {"rep": 4, "fsdp": 1, "model": 1}:
         _fail(f"expected a (rep=4, fsdp=1, model=1) mesh, got "
               f"{res.provenance['mesh']}")
@@ -196,9 +175,8 @@ def four_chips(jax, exp, dispatch, clock):
     # the same spec pinned to one chip: all four groups on device 0
     runners._protocol_mesh = lambda G: make_protocol_mesh(
         G, devices=jax.devices()[:1])
-    c0 = clock.total
     res = exp.run(spec)
-    _report("b1", res, clock.total - c0, "loss", dispatch)
+    _report("b1", res, "loss", dispatch)
     _memory(jax, "b1")
     p1 = _host_params(jax, res.state)
     eng = res.engine
@@ -258,10 +236,7 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
     print(f"compile cache: {enable_compile_cache()}")
     print(f"devices: {len(devices)} x {devices[0].device_kind}")
-    clock = _CompileClock(jax)
-    t0 = time.time()
-    (four_chips if args.chips == 4 else one_chip)(jax, exp, dispatch, clock)
-    print(f"total {time.time() - t0:.2f}s")
+    (four_chips if args.chips == 4 else one_chip)(jax, exp, dispatch)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

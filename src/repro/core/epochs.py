@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import warnings
+import weakref
 from typing import Any, Callable
 
 import jax
@@ -93,6 +94,18 @@ def clear_epoch_cache() -> None:
     _EPOCH_CACHE.clear()
 
 
+# The runner that dispatched the process's last epoch, held weakly. A profiler
+# trace names each device op by its HLO instruction only; a reader of the
+# trace lowers this runner's program again (``lower().compile().as_text()``)
+# for the op_name metadata, and so the ByzSGD stage, of each instruction.
+_last_dispatched: weakref.ref | None = None
+
+
+def last_dispatched() -> "EpochRunner | None":
+    """The runner whose epoch was dispatched last, if it is still alive."""
+    return _last_dispatched() if _last_dispatched is not None else None
+
+
 def _arg_type(x):
     """An argument as jit sees it: shape, dtype, weak type and, when
     committed, placement."""
@@ -154,8 +167,13 @@ class EpochRunner:
     # -- epoch-at-a-time API -------------------------------------------------
     def run_epoch(self, state, batches):
         """One compiled epoch over ``batches`` (leaves ``[L, n_w, ...]``).
-        ``state`` is donated. Metrics stay on device (dict of ``[L]`` bufs)."""
-        with warnings.catch_warnings():
+        ``state`` is donated. Metrics stay on device (dict of ``[L]`` bufs).
+
+        The dispatch is the profiler span ``repro/run_epoch``: on a compile
+        cache miss it holds the epoch's trace, lowering and compile."""
+        global _last_dispatched
+        with warnings.catch_warnings(), \
+                jax.profiler.TraceAnnotation("repro/run_epoch"):
             # donation is a no-op on CPU; keep that per-executable warning out
             # of benchmark output without touching the global filter state
             warnings.filterwarnings(
@@ -163,6 +181,7 @@ class EpochRunner:
             args = (state, batches, *self._extra_args())
             self._dispatched = (jax.sharding.get_mesh(),
                                 jax.tree.map(_arg_type, args))
+            _last_dispatched = weakref.ref(self)
             return self._epoch(*args)
 
     def lower(self):

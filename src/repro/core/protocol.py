@@ -41,6 +41,7 @@ drives it; the single-host ``EpochEngine`` is its correctness oracle
 """
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, NamedTuple
 
@@ -55,6 +56,28 @@ from ..agg import rules as _agg_rules
 from .attacks import ByzantineSpec, inject_gradients, inject_models
 from .epochs import EpochRunner, delivery_cache_key, fn_cache_key
 from .quorum import UniformDelivery
+
+# The stages of one ByzSGD step. Each is built under a ``jax.named_scope`` of
+# its name, so every HLO instruction's op_name metadata, and so a profiler
+# trace's ``tf_op``, says which stage made it. The ops between the stages (key
+# split, learning rate, the gather's predicate, step metrics) are unscoped.
+STAGES = ("pull", "worker_grad", "distances", "aggregate", "update", "gather")
+# a transform's wrapper around a scope in an op_name path: vmap(jvp(pull))
+_WRAPPER = re.compile(r"[\w.<>]*\((.*)\)")
+
+
+def stage_of(op_name: str) -> str | None:
+    """The stage an op was built in, from its op_name metadata (or a trace's
+    ``tf_op``): the outermost scope of the path that, stripped of transform
+    wrappers, is in :data:`STAGES`. The path's last component is the op
+    itself, not a scope; after a ``;`` come the names of ops XLA fused into
+    it. None for an op outside every stage."""
+    for scope in op_name.split(";", 1)[0].split("/")[:-1]:
+        while (m := _WRAPPER.fullmatch(scope)):
+            scope = m.group(1)
+        if scope in STAGES:
+            return scope
+    return None
 
 # ---------------------------------------------------------------------------
 # config
@@ -543,88 +566,99 @@ def make_scatter_step(bundle, pcfg: ProtocolConfig, lr_schedule,
         eta = lr_schedule(state.t).astype(jnp.float32)
 
         # 1. worker pull ------------------------------------------------------
-        models = state.params
-        if with_attack and pcfg.byz.server_attack:
-            models = inject_models(models, pcfg.byz, k_matk)
-        if pcfg.pull == "roundrobin":
-            # synchronous variant (paper §5): each worker pulls ONE model via
-            # a ring permutation over 'rep' (lowers to collective-permute,
-            # O(P) vs the Median pull's O((q-1)P)), validated by a distance
-            # filter against the worker's own replica (the Outliers filter of
-            # Eq. 14 anchored locally; on rejection the worker falls back to
-            # its own replica — a conservative, honest model by definition.
-            # The Lipschitz filter needs the previous gradient: carried only
-            # in the faithful simulator, where memory is free).
-            idx = (jnp.arange(G) + state.t + 1) % G
-            pulled = jax.tree.map(lambda l: jnp.take(l, idx, axis=0), models)
-            own = state.params
-            d2g = None
-            n2g = None
-            for pl, ow in zip(jax.tree.leaves(pulled), jax.tree.leaves(own)):
-                ax = tuple(range(1, pl.ndim))
-                d = jnp.sum((pl.astype(jnp.float32)
-                             - ow.astype(jnp.float32)) ** 2, axis=ax)
-                n = jnp.sum(ow.astype(jnp.float32) ** 2, axis=ax)
-                d2g = d if d2g is None else d2g + d
-                n2g = n if n2g is None else n2g + n
-            growth = ((3.0 * pcfg.T + 2.0) * (G - pcfg.f_workers)
-                      / (4.0 * max(pcfg.f_workers, 1)))
-            bound2 = (eta * growth) ** 2 * n2g + 1e-6
-            ok = d2g <= bound2                      # [G] per-worker verdict
+        with jax.named_scope("pull"):
+            models = state.params
+            if with_attack and pcfg.byz.server_attack:
+                models = inject_models(models, pcfg.byz, k_matk)
+            if pcfg.pull == "roundrobin":
+                # synchronous variant (paper §5): each worker pulls ONE model
+                # via a ring permutation over 'rep' (lowers to
+                # collective-permute, O(P) vs the Median pull's O((q-1)P)),
+                # validated by a distance filter against the worker's own
+                # replica (the Outliers filter of Eq. 14 anchored locally; on
+                # rejection the worker falls back to its own replica — a
+                # conservative, honest model by definition. The Lipschitz
+                # filter needs the previous gradient: carried only in the
+                # faithful simulator, where memory is free).
+                idx = (jnp.arange(G) + state.t + 1) % G
+                pulled = jax.tree.map(lambda l: jnp.take(l, idx, axis=0),
+                                      models)
+                own = state.params
+                d2g = None
+                n2g = None
+                for pl, ow in zip(jax.tree.leaves(pulled),
+                                  jax.tree.leaves(own)):
+                    ax = tuple(range(1, pl.ndim))
+                    d = jnp.sum((pl.astype(jnp.float32)
+                                 - ow.astype(jnp.float32)) ** 2, axis=ax)
+                    n = jnp.sum(ow.astype(jnp.float32) ** 2, axis=ax)
+                    d2g = d if d2g is None else d2g + d
+                    n2g = n if n2g is None else n2g + n
+                growth = ((3.0 * pcfg.T + 2.0) * (G - pcfg.f_workers)
+                          / (4.0 * max(pcfg.f_workers, 1)))
+                bound2 = (eta * growth) ** 2 * n2g + 1e-6
+                ok = d2g <= bound2                      # [G] per-worker verdict
+                pulled = jax.tree.map(
+                    lambda p, o: jnp.where(
+                        ok.reshape((G,) + (1,) * (p.ndim - 1)), p, o),
+                    pulled, own)
+            else:
+                # asynchronous variant: masked Median over the delivered quorum
+                pull_idx = delivery.pull_indices(k_pull, state.t)
+                pull_masks = jnp.zeros((G, G), bool).at[
+                    jnp.arange(G)[:, None], pull_idx].set(True)
+                pulled = masked_pull(models, pull_masks, pcfg, mesh)
             pulled = jax.tree.map(
-                lambda p, o: jnp.where(
-                    ok.reshape((G,) + (1,) * (p.ndim - 1)), p, o), pulled, own)
-        else:
-            # asynchronous variant: masked Median over the delivered quorum
-            pull_idx = delivery.pull_indices(k_pull, state.t)
-            pull_masks = jnp.zeros((G, G), bool).at[
-                jnp.arange(G)[:, None], pull_idx].set(True)
-            pulled = masked_pull(models, pull_masks, pcfg, mesh)
-        pulled = jax.tree.map(
-            lambda l: l.astype(jnp.dtype(bundle.cfg.act_dtype))
-            if l.dtype == jnp.float32 else l, pulled)
+                lambda l: l.astype(jnp.dtype(bundle.cfg.act_dtype))
+                if l.dtype == jnp.float32 else l, pulled)
 
         # 2. per-group worker gradients (vmap over 'rep'), accumulated over
         # grad_microbatches sequential micro-steps (bounds activation memory;
         # the batch arrives with a leading micro axis when n_micro > 1) ------
-        gfn = jax.vmap(jax.grad(bundle.loss),
-                       spmd_axis_name="rep" if mesh is not None else None)
-        if pcfg.grad_microbatches > 1:
-            from ..models import unroll_ctx as _uctx
+        with jax.named_scope("worker_grad"):
+            gfn = jax.vmap(jax.grad(bundle.loss),
+                           spmd_axis_name="rep" if mesh is not None else None)
+            if pcfg.grad_microbatches > 1:
+                from ..models import unroll_ctx as _uctx
 
-            if _uctx.active():  # cost-probe: vmap micro-steps (flop-identical)
-                gm = jax.vmap(gfn, in_axes=(None, 0))(pulled, batch)
-                grads = jax.tree.map(
-                    lambda x: jnp.mean(x.astype(jnp.float32), axis=0), gm)
+                # cost-probe: vmap micro-steps (flop-identical)
+                if _uctx.active():
+                    gm = jax.vmap(gfn, in_axes=(None, 0))(pulled, batch)
+                    grads = jax.tree.map(
+                        lambda x: jnp.mean(x.astype(jnp.float32), axis=0), gm)
+                else:
+                    def micro_body(acc, mb):
+                        g = gfn(pulled, mb)
+                        return jax.tree.map(
+                            lambda a, x: a + x.astype(jnp.float32)
+                            / pcfg.grad_microbatches, acc, g), None
+
+                    zeros = jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32),
+                        state.params)
+                    zeros = _constrain_like_params(zeros)
+                    grads, _ = jax.lax.scan(micro_body, zeros, batch)
             else:
-                def micro_body(acc, mb):
-                    g = gfn(pulled, mb)
-                    return jax.tree.map(
-                        lambda a, x: a + x.astype(jnp.float32)
-                        / pcfg.grad_microbatches, acc, g), None
-
-                zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-                zeros = _constrain_like_params(zeros)
-                grads, _ = jax.lax.scan(micro_body, zeros, batch)
-        else:
-            grads = gfn(pulled, batch)
-        grads = jax.tree.map(lambda g: g.astype(jnp.dtype(pcfg.exchange_dtype)),
-                             grads)
-        grads = _constrain_like_params(grads)
-        if with_attack and pcfg.byz.worker_attack:
-            grads = inject_gradients(grads, pcfg.byz, k_gatk)
+                grads = gfn(pulled, batch)
+            grads = jax.tree.map(
+                lambda g: g.astype(jnp.dtype(pcfg.exchange_dtype)), grads)
+            grads = _constrain_like_params(grads)
+            if with_attack and pcfg.byz.worker_attack:
+                grads = inject_gradients(grads, pcfg.byz, k_gatk)
 
         # 3. gradient rule (MDA by default) per server group over its quorum ---
-        push_idx = delivery.push_indices(k_push, state.t)
-        d2 = agg.rules.sqdists_from_gram(tree_gram(grads, mesh))
-        weights = quorum_weights(d2, push_idx, pcfg.f_workers, pcfg)
-        g_hat = aggregate_gradients(grads, weights, pcfg, mesh)
+        with jax.named_scope("distances"):
+            push_idx = delivery.push_indices(k_push, state.t)
+            d2 = agg.rules.sqdists_from_gram(tree_gram(grads, mesh))
+            weights = quorum_weights(d2, push_idx, pcfg.f_workers, pcfg)
+        with jax.named_scope("aggregate"):
+            g_hat = aggregate_gradients(grads, weights, pcfg, mesh)
 
         # 4. local update (paper Eq. 2 for sgd; per-replica moments ride in
         # state.opt for stateful optimizers) -----------------------------------
-        new_params, new_opt = optimizer.update(g_hat, state.opt, state.params,
-                                               eta)
+        with jax.named_scope("update"):
+            new_params, new_opt = optimizer.update(g_hat, state.opt,
+                                                   state.params, eta)
         return ByzState(params=new_params, t=state.t + 1, key=key,
                         opt=new_opt)
 
@@ -640,17 +674,20 @@ def make_gather_step(pcfg: ProtocolConfig, with_attack: bool = False,
                                            pcfg.q_servers)
 
     def gather_step(state: ByzState):
-        key, k_q, k_atk = jax.random.split(state.key, 3)
-        idx = delivery.gather_indices(k_q, state.t)
-        masks = jnp.zeros((G, G), bool).at[jnp.arange(G)[:, None], idx].set(True)
-        models = state.params
-        if with_attack and pcfg.byz.server_attack:
-            models = inject_models(models, pcfg.byz, k_atk)
-        new_params = masked_pull(models, masks, pcfg, mesh,
-                                 rule=pcfg.gather_gar)
-        new_params = jax.tree.map(lambda n, p: n.astype(p.dtype),
-                                  new_params, state.params)
-        return ByzState(params=new_params, t=state.t, key=key, opt=state.opt)
+        with jax.named_scope("gather"):
+            key, k_q, k_atk = jax.random.split(state.key, 3)
+            idx = delivery.gather_indices(k_q, state.t)
+            masks = jnp.zeros((G, G), bool).at[
+                jnp.arange(G)[:, None], idx].set(True)
+            models = state.params
+            if with_attack and pcfg.byz.server_attack:
+                models = inject_models(models, pcfg.byz, k_atk)
+            new_params = masked_pull(models, masks, pcfg, mesh,
+                                     rule=pcfg.gather_gar)
+            new_params = jax.tree.map(lambda n, p: n.astype(p.dtype),
+                                      new_params, state.params)
+            return ByzState(params=new_params, t=state.t, key=key,
+                            opt=state.opt)
 
     return gather_step
 
