@@ -1,11 +1,14 @@
 """Backend dispatch: route each aggregation primitive to its pure-jnp
 reference or its Pallas kernel.
 
-Three primitives have Pallas implementations under ``repro.kernels``:
+Four primitives have Pallas implementations under ``repro.kernels``:
 
   * ``pairwise_sqdist``  — Gram-matrix kernel, feeds every distance-based rule
   * ``mda_diameter``     — subset-diameter scan for exact MDA selection
   * ``cwise_median``     — per-coordinate median over a replica stack (n <= 64)
+  * ``masked_median``    — every receiver's masked median of a replica stack
+    in one pass, written in the receivers' dtype (the protocol's pull and
+    DMC gather)
 
 ``backend`` is one of:
 
@@ -134,6 +137,28 @@ def cwise_median(x: jax.Array, *, backend: str | None = None,
         from ..kernels.cwise_median import ops
         return ops.cwise_median(x, interpret=interpret)
     return rules.coordinate_median(x)
+
+
+def masked_median_views(x: jax.Array, masks: jax.Array, out_dtype, *,
+                        fallback=None, backend: str | None = None,
+                        interpret: bool | None = None) -> jax.Array:
+    """[G_send, ...] stack, [G_recv, G_send] bool masks -> [G_recv, ...] in
+    ``out_dtype``: each receiver's coordinate-wise median of the senders it
+    got (``rules.masked_coordinate_median``, then the cast). The kernel
+    takes a float stack that ``rules.sort_stack`` sorts with its network;
+    the jnp side is ``fallback(x)`` where the caller gives one (its own
+    route, e.g. streamed), else one vmap over the receivers."""
+    ok = (jnp.issubdtype(x.dtype, jnp.floating)
+          and rules.network_sorts(x.shape[0]))
+    if _resolve("masked_median", backend, pallas_ok=ok) == "pallas":
+        from ..kernels.cwise_median import ops
+        return ops.masked_median_views(x, masks, out_dtype,
+                                       interpret=interpret)
+    if fallback is not None:
+        return fallback(x)
+    xf = x.astype(jnp.float32)
+    return jax.vmap(lambda m: rules.masked_coordinate_median(xf, m))(
+        masks).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,11 @@ call-site special case in the codebase:
   * ``tree_mode`` — how the rule extends to pytrees: ``"leafwise"`` for
     coordinate-wise rules, ``"selection"`` for weights-based rules, ``None``
     for rules without a sound pytree decomposition (Bulyan).
+  * ``masked_views`` — a dispatch-level entry that computes every
+    receiver's masked aggregate of a whole leaf in one call, in the
+    receivers' dtype (``(x, masks, out_dtype, *, fallback=None, backend=,
+    interpret=)``), which the protocol's pull and gather take where the
+    backend resolves to its kernel.
 
 Lookup is by name (:func:`get`); ``f`` bounds are validated uniformly at call
 time from the spec's mechanical requirement with a uniform error message.
@@ -56,6 +61,8 @@ class Aggregator:
     tree_mode: str | None = "leafwise"      # 'leafwise' | 'selection' | None
     backends: tuple[str, ...] = ("jnp",)
     masked_fn: Callable | None = None       # traced-ok: (x, [f,] mask) -> [d]
+    masked_views: Callable | None = None    # (x, masks [r, n], out_dtype)
+                                            # -> [r, ...], one call
     weights_from_d2: Callable | None = None  # (d2, f, *, mask=None, **kw)->[n]
     tunables: frozenset[str] = frozenset()  # extra kwargs the rule accepts
 
@@ -170,7 +177,8 @@ register(Aggregator(
     breakdown="n >= 2f+1", requires=(2, 1),
     doc="coordinate-wise median (server-model DMC rule)",
     backends=("jnp", "pallas"),
-    masked_fn=rules.masked_coordinate_median))
+    masked_fn=rules.masked_coordinate_median,
+    masked_views=dispatch.masked_median_views))
 
 register(Aggregator(
     name="meamed", fn=dispatch.meamed, takes_f=True,

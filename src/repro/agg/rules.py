@@ -247,7 +247,7 @@ def use_sort_network(on: bool):
 
 
 @lru_cache(maxsize=None)
-def _oddeven_pairs(n: int) -> tuple[tuple[int, int], ...]:
+def oddeven_pairs(n: int) -> tuple[tuple[int, int], ...]:
     """Batcher odd-even merge-sort compare-exchange schedule for arbitrary n."""
     pairs = []
     p = 1
@@ -261,6 +261,12 @@ def _oddeven_pairs(n: int) -> tuple[tuple[int, int], ...]:
             k //= 2
         p *= 2
     return tuple(pairs)
+
+
+def network_sorts(n: int) -> bool:
+    """Whether :func:`sort_stack` sorts ``n`` rows with the compare-exchange
+    network (else with ``jnp.sort``, whose NaNs sort last as NaN)."""
+    return n <= _NETWORK_MAX_N and sort_network_enabled()
 
 
 def sort_stack(x: jax.Array) -> jax.Array:
@@ -280,7 +286,7 @@ def sort_stack(x: jax.Array) -> jax.Array:
     n = x.shape[0]
     if n <= 1:
         return x
-    if n > _NETWORK_MAX_N or not sort_network_enabled():
+    if not network_sorts(n):
         return jnp.sort(x, axis=0)
     # min/max would smear a single NaN across every rank; map NaN to the
     # finite _BIG sentinel first so Byzantine NaN payloads sort last exactly
@@ -288,7 +294,7 @@ def sort_stack(x: jax.Array) -> jax.Array:
     if jnp.issubdtype(x.dtype, jnp.floating):
         x = jnp.where(jnp.isnan(x), jnp.asarray(_BIG, x.dtype), x)
     rows = list(x)
-    for i, j in _oddeven_pairs(n):
+    for i, j in oddeven_pairs(n):
         a, b = rows[i], rows[j]
         rows[i] = jnp.minimum(a, b)
         rows[j] = jnp.maximum(a, b)

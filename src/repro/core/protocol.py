@@ -41,7 +41,9 @@ drives it; the single-host ``EpochEngine`` is its correctness oracle
 """
 from __future__ import annotations
 
+import contextvars
 import re
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, NamedTuple
 
@@ -426,16 +428,43 @@ def _leaf_stream(fn, chunk_bytes: int, mesh=None):
 # ---------------------------------------------------------------------------
 
 
+# the dtype masked_pull returns float32 leaves' views in (views_in); None
+# keeps each leaf's dtype
+_VIEW_DTYPE = contextvars.ContextVar("view_dtype", default=None)
+
+
+@contextmanager
+def views_in(dtype):
+    """Within the block, :func:`masked_pull` returns the views of float32
+    leaves in ``dtype``. The pull hands its compute dtype down this way, so
+    that the Median kernel writes it directly, while masked_pull keeps the
+    signature ``(params, masks, cfg, mesh, rule)`` that stand-ins of it
+    share."""
+    token = _VIEW_DTYPE.set(jnp.dtype(dtype))
+    try:
+        yield
+    finally:
+        _VIEW_DTYPE.reset(token)
+
+
 def masked_pull(params, masks, cfg: ProtocolConfig, mesh=None, rule=None):
     """Per-receiver masked aggregation over the replica axis.
 
     params leaves [G, ...]; masks [G_recv, G_send] bool. Returns leaves
-    [G_recv, ...] — worker/server g's aggregated view of the replicas.
-    The rule defaults to ``cfg.pull_gar`` (any registered rule with
-    traced-mask support), the paper's Median; the DMC gather passes
-    ``cfg.gather_gar``.
+    [G_recv, ...] — worker/server g's aggregated view of the replicas, in
+    the leaf's dtype or, for float32 leaves, an enclosing
+    :func:`views_in`'s. The rule defaults to ``cfg.pull_gar`` (any
+    registered rule with traced-mask support), the paper's Median; the DMC
+    gather passes ``cfg.gather_gar``.
+
+    Where the rule has ``masked_views`` and the replicas live whole on one
+    device (no mesh, or a mesh of one), each leaf goes to it in one call:
+    where the backend resolves to its kernel, that reads each replica once
+    and writes each view once, in its dtype. Its jnp side, and every other
+    case, stream the leaf through the rule (``_leaf_stream``) and cast.
     """
     spec = agg.get(rule or cfg.pull_gar)
+    whole = mesh is None or mesh.size == 1
 
     def med_chunk(chunk):  # [G, ...]
         def one(mask):
@@ -446,7 +475,21 @@ def masked_pull(params, masks, cfg: ProtocolConfig, mesh=None, rule=None):
                 out, NamedSharding(mesh, P("rep", *body_spec(out.shape[1:], mesh))))
         return out
 
-    op = _leaf_stream(med_chunk, cfg.chunk_bytes, mesh)
+    stream = _leaf_stream(med_chunk, cfg.chunk_bytes, mesh)
+
+    view_dtype = _VIEW_DTYPE.get()
+
+    def op(leaf):
+        dt = (view_dtype if view_dtype is not None
+              and leaf.dtype == jnp.float32 else leaf.dtype)
+
+        def streamed(l):
+            return stream(l).astype(dt)
+
+        if spec.masked_views is not None and whole:
+            return spec.masked_views(leaf, masks, dt, fallback=streamed)
+        return streamed(leaf)
+
     return jax.tree.map(op, params)
 
 
@@ -607,7 +650,8 @@ def make_scatter_step(bundle, pcfg: ProtocolConfig, lr_schedule,
                 pull_idx = delivery.pull_indices(k_pull, state.t)
                 pull_masks = jnp.zeros((G, G), bool).at[
                     jnp.arange(G)[:, None], pull_idx].set(True)
-                pulled = masked_pull(models, pull_masks, pcfg, mesh)
+                with views_in(bundle.cfg.act_dtype):
+                    pulled = masked_pull(models, pull_masks, pcfg, mesh)
             pulled = jax.tree.map(
                 lambda l: l.astype(jnp.dtype(bundle.cfg.act_dtype))
                 if l.dtype == jnp.float32 else l, pulled)

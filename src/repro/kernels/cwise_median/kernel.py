@@ -11,6 +11,12 @@ reduce the sorted rows.
 
 n is padded to the next power of two with +inf rows; since pads sort last,
 the statistics of the n real rows live in the first n sorted rows.
+
+The masked Median (``masked_median_pallas_call``) is the protocol's pull and
+DMC gather: for every receiver at once, the median of the senders its
+delivery mask holds, over a whole leaf. It shares the compare-exchange loop
+and sorts with Batcher's odd-even network of ``agg.rules.sort_stack``, which
+needs no padding rows, so its views equal the jnp route's bit for bit.
 """
 from __future__ import annotations
 
@@ -19,6 +25,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...agg.rules import oddeven_pairs
+
+_BIG = 3.4e38  # finite sentinel (f32 max ~3.4e38): NaN, pad lanes and
+               # undelivered rows sort last
 
 
 def bitonic_pairs(n_pow2: int):
@@ -40,15 +52,22 @@ def bitonic_pairs(n_pow2: int):
     return pairs
 
 
+def _compare_exchange(rows, pairs):
+    """Sort equal-shaped arrays elementwise through a static network of
+    (lower, upper) index pairs: the lower takes the min, the upper the max."""
+    rows = list(rows)
+    for lo_i, hi_i in pairs:
+        a, b = rows[lo_i], rows[hi_i]
+        rows[lo_i] = jnp.minimum(a, b)
+        rows[hi_i] = jnp.maximum(a, b)
+    return rows
+
+
 def _sorted_rows(x_ref, n_pow2: int):
     """Sort the tile's row axis through the shared bitonic network."""
-    rows = [x_ref[i, :] for i in range(n_pow2)]  # each [block_d]
-    for stage in bitonic_pairs(n_pow2):
-        for (lo_i, hi_i) in stage:
-            a, b = rows[lo_i], rows[hi_i]
-            rows[lo_i] = jnp.minimum(a, b)
-            rows[hi_i] = jnp.maximum(a, b)
-    return rows
+    return _compare_exchange(
+        [x_ref[i, :] for i in range(n_pow2)],  # each [block_d]
+        [pair for stage in bitonic_pairs(n_pow2) for pair in stage])
 
 
 def _median_kernel(x_ref, o_ref, *, n: int, n_pow2: int):
@@ -138,3 +157,83 @@ def meamed_pallas_call(n: int, f: int, n_pow2: int, d_pad: int,
                        block_d: int, interpret: bool = False):
     return _rule_pallas_call(_meamed_kernel, n_pow2, d_pad, block_d,
                              interpret, n=n, f=f)
+
+
+def _masked_median_kernel(masks_ref, x_ref, o_ref, *, n_send: int,
+                          n_recv: int, sub_rows: int, sub_cols: int):
+    """Every receiver's masked coordinate-wise median of one tile.
+
+    ``masks_ref`` (SMEM, int32 [n_recv * n_send]) holds the delivery masks
+    row by row; ``x_ref`` the senders' [n_send, br, bc] tile, read once;
+    ``o_ref`` the receivers' [n_recv, br, bc] views. The arithmetic is
+    ``agg.rules.masked_coordinate_median``'s: NaN and undelivered values
+    become the finite sentinel, Batcher's network sorts the n_send rows,
+    and the mean of ranks (q-1)//2 and q//2 (q the receiver's delivered
+    count) is cast once to the output dtype. The tile is walked in
+    [sub_rows, sub_cols] pieces, so the rows in flight stay in registers."""
+    pairs = oddeven_pairs(n_send)
+    big = jnp.float32(_BIG)
+    delivered, lo, hi = [], [], []
+    for r in range(n_recv):
+        m = [masks_ref[r * n_send + s] for s in range(n_send)]
+        q = sum(m[1:], m[0])
+        delivered.append([v != 0 for v in m])
+        lo.append((q - 1) >> 1)             # floor((q-1)/2), -1 for q = 0
+        hi.append(q >> 1)
+    n_rows, n_cols = x_ref.shape[1] // sub_rows, x_ref.shape[2] // sub_cols
+
+    def start(k, size, n):      # static where the tile is one piece wide
+        return 0 if n == 1 else pl.multiple_of(k * size, size)
+
+    def piece(i, carry):
+        at = (pl.ds(start(i // n_cols, sub_rows, n_rows), sub_rows),
+              pl.ds(start(i % n_cols, sub_cols, n_cols), sub_cols))
+        xs = []
+        for s in range(n_send):
+            v = x_ref[(s,) + at].astype(jnp.float32)
+            xs.append(jnp.where(jnp.isnan(v), big, v))
+        for r in range(n_recv):
+            rows = _compare_exchange(
+                [jnp.where(delivered[r][s], xs[s], big)
+                 for s in range(n_send)], pairs)
+            a = b = rows[0]
+            for k in range(1, n_send):
+                a = jnp.where(lo[r] == k, rows[k], a)
+                b = jnp.where(hi[r] == k, rows[k], b)
+            o_ref[(r,) + at] = (0.5 * (a + b)).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n_rows * n_cols, piece, 0)
+
+
+def masked_median_pallas_call(n_send: int, n_recv: int, rows: int,
+                              cols: int, block: tuple[int, int],
+                              sub: tuple[int, int], out_dtype,
+                              in_place: bool = False,
+                              interpret: bool = False):
+    """[n_recv * n_send] int32 masks, [n_send, rows, cols] senders ->
+    [n_recv, rows, cols] views in ``out_dtype``. ``block`` (br, bc) is a
+    grid step's tile; ragged edge tiles are clipped by the pipeline;
+    ``sub`` divides ``block``. ``in_place`` (senders and views of one shape
+    and dtype) writes the views over the senders' buffer: a grid step reads
+    its tile whole before it writes the tile's views, and tiles do not
+    overlap. Where the senders are dead after the call, as the replicas
+    after the DMC gather, no copy of them is made."""
+    br, bc = block
+    return pl.pallas_call(
+        partial(_masked_median_kernel, n_send=n_send, n_recv=n_recv,
+                sub_rows=sub[0], sub_cols=sub[1]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
+            in_specs=[pl.BlockSpec((n_send, br, bc),
+                                   lambda i, j, m: (0, i, j))],
+            out_specs=pl.BlockSpec((n_recv, br, bc),
+                                   lambda i, j, m: (0, i, j))),
+        out_shape=jax.ShapeDtypeStruct((n_recv, rows, cols), out_dtype),
+        input_output_aliases={1: 0} if in_place else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="masked_median",
+    )

@@ -4,20 +4,26 @@ The Pallas backends of the ``median``, ``trimmed_mean`` and ``meamed``
 aggregators (one shared bitonic sorting network, three reductions); call
 sites reach them through ``repro.agg`` dispatch (``backend="pallas"`` or
 auto on TPU), which falls back to the jnp reference for stacks larger than
-the kernels' n <= 64 limit.
+the kernels' n <= 64 limit. ``masked_median_views`` is the ``median`` rule's
+masked form over a whole leaf: every receiver's view in one pass.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from .kernel import (meamed_pallas_call, median_pallas_call,
-                     trimmed_mean_pallas_call)
+from .kernel import (_BIG, masked_median_pallas_call, meamed_pallas_call,
+                     median_pallas_call, trimmed_mean_pallas_call)
 
 _LANE = 128
-_BIG = 3.4e38  # finite sentinel (f32 max ~3.4e38): NaN/pad lanes sort last
+# the masked Median's tiles: a leaf's minor dim up to this many lanes stays
+# whole in a tile, and a grid step's input and output tiles, double-buffered,
+# take at most this many bytes of VMEM
+_MAX_LANES = 4096
+_TILE_BYTES = 8 * 2**20
 
 
 def _default_interpret() -> bool:
@@ -88,3 +94,51 @@ def cwise_meamed(x: jax.Array, f: int, *, block_d: int = 1024,
     xp, n_pow2, d_pad, block_d = _tile(x, block_d)
     out = meamed_pallas_call(n, f, n_pow2, d_pad, block_d, interpret)(xp)
     return out[0, :d]
+
+
+def _masked_tiles(rows: int, cols: int, n_send: int, n_recv: int,
+                  in_bytes: int, out_bytes: int, tile_bytes: int):
+    """(block, sub) of the masked Median over a [rows, cols] view: the
+    grid step's (br, bc) tile and the [sub_rows, sub_cols] piece the kernel
+    computes at a time. A minor dim up to ``_MAX_LANES`` stays whole, a
+    wider one is cut at a lane-aligned divisor (or at ``_MAX_LANES``, with
+    a ragged edge tile); rows fill the VMEM budget in multiples of 16 (a
+    bfloat16 tile's sublanes), or are taken whole."""
+    if cols <= _MAX_LANES:
+        bc = cols
+    elif cols % _LANE == 0:
+        bc = max(c for c in range(_LANE, _MAX_LANES + 1, _LANE)
+                 if cols % c == 0)
+    else:
+        bc = _MAX_LANES
+    per_row = 2 * bc * (n_send * in_bytes + n_recv * out_bytes)
+    br = max(tile_bytes // per_row // 16 * 16, 16)
+    if rows <= br:
+        br = rows
+    sub_rows = next((r for r in (16, 8) if br % r == 0), br)
+    sub_cols = next((c for c in (512, 256, _LANE) if bc % c == 0), bc)
+    return (br, bc), (sub_rows, sub_cols)
+
+
+@partial(jax.jit, static_argnames=("out_dtype", "interpret", "tile_bytes"))
+def masked_median_views(x: jax.Array, masks: jax.Array, out_dtype, *,
+                        interpret: bool | None = None,
+                        tile_bytes: int = _TILE_BYTES) -> jax.Array:
+    """[G_send, ...] float stack, [G_recv, G_send] bool delivery masks ->
+    [G_recv, ...] in ``out_dtype``: each receiver's coordinate-wise median
+    of the senders it got (``agg.rules.masked_coordinate_median``, then the
+    cast). The leaf is viewed as [G_send, R, C], its minor dim kept and the
+    leading body dims merged, so the view needs no relayout."""
+    if interpret is None:
+        interpret = _default_interpret()
+    n_send, body = x.shape[0], x.shape[1:]
+    n_recv = masks.shape[0]
+    rows, cols = math.prod(body[:-1]), (body[-1] if body else 1)
+    out_dtype = jnp.dtype(out_dtype)
+    block, sub = _masked_tiles(rows, cols, n_send, n_recv, x.dtype.itemsize,
+                               out_dtype.itemsize, tile_bytes)
+    in_place = n_recv == n_send and x.dtype == out_dtype
+    out = masked_median_pallas_call(n_send, n_recv, rows, cols, block, sub,
+                                    out_dtype, in_place, interpret)(
+        masks.astype(jnp.int32).reshape(-1), x.reshape(n_send, rows, cols))
+    return out.reshape((n_recv,) + body)

@@ -155,6 +155,28 @@ def test_cwise_rules_compile(one_chip, rule):
     assert "tpu_custom_call" in _compile(fns[rule], x)
 
 
+# phi4_mini_cut's leaves at G=4: an MLP matrix of the two scanned layers,
+# the embedding table, the layers' norm scales
+@pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["pull", "gather"])
+@pytest.mark.parametrize("shape", [(4, 2, 3072, 8192), (4, 16384, 3072),
+                                   (4, 2, 3072)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_masked_median_compiles(one_chip, shape, out_dtype):
+    """The pull's views in bfloat16 and the gather's in float32, within the
+    default scoped VMEM. The leaf goes to the kernel as it is, and the
+    gather's views overwrite replicas that are dead after it: no copy."""
+    x = _sds(shape, jnp.float32, one_chip)
+    masks = _sds((4, 4), jnp.bool_, one_chip)
+    text = jax.jit(lambda x, m: med_ops.masked_median_views(
+        x, m, out_dtype, interpret=False), donate_argnums=0).lower(
+            x, masks).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "masked_median" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert "copy(" not in text and "transpose(" not in text
+
+
 def test_gram_compiles(one_chip):
     x = _sds((_N, _D), jnp.float32, one_chip)
     assert "tpu_custom_call" in _compile(
