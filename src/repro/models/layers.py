@@ -51,6 +51,17 @@ def layernorm(p, x, eps=1e-5):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def groupnorm(p, x, groups: int, eps=1e-5):
+    """GroupNorm over the last dim split into ``groups`` equal groups, each
+    normalised on its own; scale and bias (``init_layernorm``) per channel."""
+    xf = x.astype(jnp.float32)
+    g = xf.reshape(*x.shape[:-1], groups, x.shape[-1] // groups)
+    mu = jnp.mean(g, axis=-1, keepdims=True)
+    var = jnp.var(g, axis=-1, keepdims=True)
+    g = ((g - mu) * jax.lax.rsqrt(var + eps)).reshape(x.shape)
+    return (g * p["scale"] + p["bias"]).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # RoPE (incl. M-RoPE for qwen2-vl)
 # ---------------------------------------------------------------------------

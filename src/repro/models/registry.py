@@ -36,6 +36,8 @@ _CONFIG_MODULES = {
     "phi4-mini-3.8b": "repro.configs.phi4_mini_3p8b",
     "internlm2-20b": "repro.configs.internlm2_20b",
     "rwkv6-3b": "repro.configs.rwkv6_3b",
+    # not in ARCH_IDS: the benchmark's Finch cut (bench/configs)
+    "rwkv6-1.6b": "repro.configs.rwkv6_1b6",
     "qwen2-vl-7b": "repro.configs.qwen2_vl_7b",
     "whisper-small": "repro.configs.whisper_small",
 }

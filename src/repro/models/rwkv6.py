@@ -1,18 +1,26 @@
-"""RWKV6 ("Finch") — attention-free RNN with data-dependent per-channel decay.
+"""RWKV6 ("Finch") — attention-free RNN with data-dependent per-channel decay
+(arXiv:2404.05892; RWKV-LM's ``RWKV_Tmix_x060`` / ``RWKV_CMix_x060``).
 
 Per head (K = V = head dim):
     y_t = r_t . (S_{t-1} + diag(u * k_t) v_t),   S_t = diag(d_t) S_{t-1} + k_t (x) v_t
-with d_t = exp(-exp(w_t)) and w_t = w0 + tanh(x_t A_w) B_w — the paper-defining
-*data-dependent decay* (arXiv:2404.05892). Training uses a chunked scan: the
-intra-chunk pairwise decay tensor is computed exactly in log-space
-(exp(L_{t-1}-L_j) <= 1 for j < t, so no overflow), chunk=16 keeps the
-[B,H,C,C,K] transient at tens of MB. Decode is the O(1)-state recurrence =>
-long_500k serve_step is sub-quadratic.
+with d_t = exp(-exp(w_t)) and w_t = w0 + tanh(x^w_t A_w) B_w — the *data-
+dependent decay*. The token shift is Finch's ddlerp: with xx = shift(x) - x,
+m = tanh((x + xx * maa_x) W1) split into five rank-32 parts, each times its
+W2, and x^* = x + xx * (maa_* + m_*) for * in w, k, v, r, g. The WKV output
+goes through a per-head GroupNorm (eps 1e-5 * head_size_divisor^2) and the
+gate silu(x^g Wg) before the output projection. The model puts a LayerNorm
+(``ln0``) on the embedding before the first block and reads its logits from
+an untied head unless ``tie_embeddings``.
 
-Simplification vs the reference implementation (documented): the five token-
-shift interpolation weights (mu_r/k/v/w/g) are static per-channel parameters
-(RWKV6 makes them data-dependent via a small LoRA as well); the decay LoRA —
-the architecturally defining piece — is implemented in full.
+Training uses a chunked scan: the intra-chunk pairwise decay tensor is
+computed exactly in log-space (exp(L_{t-1}-L_j) <= 1 for j < t, so no
+overflow). Each chunk is rematerialised in the backward pass, so the scan
+keeps one [B,H,K,V] state per chunk and recomputes the chunk's [B,H,C,C,K]
+decay tensor, instead of keeping it for every chunk of every layer. Decode is
+the O(1)-state recurrence => long_500k serve_step is sub-quadratic.
+
+Departure from the published model: a log decay below ``LOG_DECAY_FLOOR``
+(-20) is taken as -20, i.e. a decay below e^-20 ~ 2e-9 is taken as e^-20.
 """
 from __future__ import annotations
 
@@ -20,6 +28,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import layers as L
 from .unroll_ctx import scan as uscan
@@ -27,7 +36,10 @@ from .config import ArchConfig
 from .sharding import shard
 
 LOG_DECAY_FLOOR = -20.0
-DECAY_LORA = 64
+MIX_LORA = 32            # rank of each of ddlerp's five mixes
+DECAY_LORA = 64          # rank of the decay's LoRA
+HEAD_SIZE_DIVISOR = 8    # ln_x's eps is norm_eps * HEAD_SIZE_DIVISOR ** 2
+MIXES = ("w", "k", "v", "r", "g")
 
 
 class RwkvCache(NamedTuple):
@@ -45,29 +57,32 @@ def dims(cfg: ArchConfig):
 def init_block(key, cfg: ArchConfig):
     D, F = cfg.d_model, cfg.d_ff
     H, K = dims(cfg)
-    ks = jax.random.split(key, 10)
-    mu = lambda k: jax.random.uniform(k, (D,), jnp.float32)
+    ks = iter(jax.random.split(key, 20))
+    mu = lambda: jax.random.uniform(next(ks), (D,), jnp.float32)
     return {
         "ln1": L.init_layernorm(D),
         "ln2": L.init_layernorm(D),
-        "mu_r": mu(ks[0]), "mu_k": mu(ks[1]), "mu_v": mu(ks[2]),
-        "mu_w": mu(ks[3]), "mu_g": mu(ks[4]),
-        "Wr": L._init_dense(ks[5], D, D, D),
-        "Wk": L._init_dense(ks[6], D, D, D),
-        "Wv": L._init_dense(ks[7], D, D, D),
-        "Wg": L._init_dense(ks[8], D, D, D),
+        # ddlerp: RWKV-LM's init, W1 zero and W2 small
+        "maa_x": mu(), **{f"maa_{m}": mu() for m in MIXES},
+        "maa_w1": jnp.zeros((D, len(MIXES) * MIX_LORA), jnp.float32),
+        "maa_w2": jax.random.uniform(next(ks), (len(MIXES), MIX_LORA, D),
+                                     jnp.float32, -0.01, 0.01),
+        "Wr": L._init_dense(next(ks), D, D, D),
+        "Wk": L._init_dense(next(ks), D, D, D),
+        "Wv": L._init_dense(next(ks), D, D, D),
+        "Wg": L._init_dense(next(ks), D, D, D),
         "w0": jnp.full((D,), 1.0, jnp.float32),   # exp(1) ~ strong decay init
-        "wA": L._init_dense(ks[9], D, D, DECAY_LORA),
+        "wA": L._init_dense(next(ks), D, D, DECAY_LORA),
         "wB": jnp.zeros((DECAY_LORA, D), jnp.float32),
-        "u": (0.1 * jax.random.normal(jax.random.fold_in(key, 11), (H, K))).astype(jnp.float32),
+        "u": (0.1 * jax.random.normal(next(ks), (H, K))).astype(jnp.float32),
         "ln_x": L.init_layernorm(D),
-        "Wo": L._init_dense(jax.random.fold_in(key, 12), D, D, D),
+        "Wo": L._init_dense(next(ks), D, D, D),
         # channel mix
-        "mu_ck": mu(jax.random.fold_in(key, 13)),
-        "mu_cr": mu(jax.random.fold_in(key, 14)),
-        "cWk": L._init_dense(jax.random.fold_in(key, 15), D, D, F),
-        "cWv": L._init_dense(jax.random.fold_in(key, 16), F, F, D),
-        "cWr": L._init_dense(jax.random.fold_in(key, 17), D, D, D),
+        "mu_ck": mu(),
+        "mu_cr": mu(),
+        "cWk": L._init_dense(next(ks), D, D, F),
+        "cWv": L._init_dense(next(ks), F, F, D),
+        "cWr": L._init_dense(next(ks), D, D, D),
     }
 
 
@@ -88,7 +103,7 @@ def wkv_chunked(r, k, v, lw, u, s0, chunk: int = 16):
     resh = lambda a: a.reshape(Bsz, nch, chunk, H, K).transpose(1, 0, 3, 2, 4)
     rc, kc, vc, lwc = resh(r), resh(k), resh(v), resh(lw)  # [nch,B,H,C,K]
 
-    mask_lt = jnp.tril(jnp.ones((chunk, chunk), bool), k=-1)  # strict j < t
+    mask_lt = np.tril(np.ones((chunk, chunk), bool), k=-1)  # strict j < t
 
     def body(s, xs):
         rr, kk, vv, ww = xs                      # [B,H,C,K]
@@ -122,24 +137,39 @@ def wkv_chunked(r, k, v, lw, u, s0, chunk: int = 16):
              vc.astype(jnp.float32), lwc))
         s_fin = s0.astype(jnp.float32)
     else:
-        s_fin, ys = jax.lax.scan(body, s0.astype(jnp.float32),
-                                 (rc.astype(jnp.float32), kc.astype(jnp.float32),
+        # rematerialised: the backward keeps each chunk's carried state and
+        # inputs, and recomputes its [B,H,C,C,K] decay tensor
+        s_fin, ys = jax.lax.scan(jax.checkpoint(body, prevent_cse=False),
+                                 s0.astype(jnp.float32),
+                                 (rc.astype(jnp.float32),
+                                  kc.astype(jnp.float32),
                                   vc.astype(jnp.float32), lwc))
     y = ys.transpose(1, 0, 3, 2, 4).reshape(Bsz, nch * chunk, H, K)
     return y[:, :S], s_fin
+
+
+def _ddlerp(p, x, xx, dtype):
+    """Finch's data-dependent token shift: x + xx * (maa_* + m_*) for each
+    of ``MIXES``, where xx = shift(x) - x."""
+    B, S, D = x.shape
+    xxx = x + xx * p["maa_x"].astype(dtype)
+    m = jnp.tanh(xxx @ p["maa_w1"].astype(dtype))
+    m = jnp.einsum("bsfr,frd->fbsd", m.reshape(B, S, len(MIXES), MIX_LORA),
+                   p["maa_w2"].astype(dtype))
+    return {n: x + xx * (p[f"maa_{n}"].astype(dtype) + m[i])
+            for i, n in enumerate(MIXES)}
 
 
 def time_mix(p, x, cfg: ArchConfig, dtype, cache: RwkvCache | None):
     B, S, D = x.shape
     H, K = dims(cfg)
     last = cache.shift_t if cache is not None else jnp.zeros((B, D), x.dtype)
-    xp = _shift(x, last)
-    lerp = lambda mu: x + (xp - x) * mu.astype(dtype)
-    r = (lerp(p["mu_r"]) @ p["Wr"].astype(dtype)).reshape(B, S, H, K)
-    k = (lerp(p["mu_k"]) @ p["Wk"].astype(dtype)).reshape(B, S, H, K)
-    v = (lerp(p["mu_v"]) @ p["Wv"].astype(dtype)).reshape(B, S, H, K)
-    g = lerp(p["mu_g"]) @ p["Wg"].astype(dtype)
-    xw = lerp(p["mu_w"]).astype(jnp.float32)
+    xs = _ddlerp(p, x, _shift(x, last) - x, dtype)
+    r = (xs["r"] @ p["Wr"].astype(dtype)).reshape(B, S, H, K)
+    k = (xs["k"] @ p["Wk"].astype(dtype)).reshape(B, S, H, K)
+    v = (xs["v"] @ p["Wv"].astype(dtype)).reshape(B, S, H, K)
+    g = xs["g"] @ p["Wg"].astype(dtype)
+    xw = xs["w"].astype(jnp.float32)
     wlog = p["w0"] + jnp.tanh(xw @ p["wA"]) @ p["wB"]          # [B,S,D]
     lw = jnp.maximum(-jnp.exp(wlog), LOG_DECAY_FLOOR).reshape(B, S, H, K)
 
@@ -154,9 +184,10 @@ def time_mix(p, x, cfg: ArchConfig, dtype, cache: RwkvCache | None):
                  + jnp.einsum("bhk,bhv->bhkv", kk, vv))
         y = y[:, None]
     else:
-        y, s_fin = wkv_chunked(r, k, v, lw, p["u"], s0)
-    y = y.reshape(B, S, D).astype(dtype)
-    y = L.layernorm(p["ln_x"], y, cfg.norm_eps)  # group-norm stand-in
+        with jax.named_scope("wkv"):
+            y, s_fin = wkv_chunked(r, k, v, lw, p["u"], s0)
+    y = L.groupnorm(p["ln_x"], y.reshape(B, S, D), H,
+                    cfg.norm_eps * HEAD_SIZE_DIVISOR ** 2).astype(dtype)
     out = (y * jax.nn.silu(g)) @ p["Wo"].astype(dtype)
     new_shift = x[:, -1]
     return out, new_shift, s_fin
@@ -195,16 +226,30 @@ def init_cache(cfg: ArchConfig, batch: int, dtype=jnp.bfloat16) -> RwkvCache:
 # -- full model ---------------------------------------------------------------
 
 def init(key, cfg: ArchConfig):
-    ke, kb = jax.random.split(key)
+    ke, kb, kh = jax.random.split(key, 3)
     bkeys = jax.random.split(kb, cfg.n_layers)
     blocks = jax.vmap(lambda k: init_block(k, cfg))(bkeys)
-    return {"embed": L.init_embedding(ke, cfg.vocab, cfg.d_model),
-            "blocks": blocks, "ln_f": L.init_layernorm(cfg.d_model)}
+    params = {"embed": L.init_embedding(ke, cfg.vocab, cfg.d_model),
+              "ln0": L.init_layernorm(cfg.d_model),
+              "blocks": blocks, "ln_f": L.init_layernorm(cfg.d_model)}
+    if not cfg.tie_embeddings:
+        params["head"] = {"table": L._init_dense(kh, cfg.d_model, cfg.vocab,
+                                                 cfg.d_model)}
+    return params
+
+
+def _head(params, cfg: ArchConfig):
+    return params["embed"] if cfg.tie_embeddings else params["head"]
+
+
+def _embed(params, tokens, cfg: ArchConfig, dtype):
+    x = L.embed(params["embed"], tokens, dtype)
+    return shard(L.layernorm(params["ln0"], x, cfg.norm_eps), "act_btd")
 
 
 def forward(params, tokens, *, cfg: ArchConfig, remat: bool = True):
     dtype = jnp.dtype(cfg.act_dtype)
-    x = shard(L.embed(params["embed"], tokens, dtype), "act_btd")
+    x = _embed(params, tokens, cfg, dtype)
 
     def body(blk, x):
         return block(blk, x, cfg, dtype)[0]
@@ -221,7 +266,7 @@ def forward(params, tokens, *, cfg: ArchConfig, remat: bool = True):
 
 def loss(params, batch, *, cfg: ArchConfig):
     hidden = forward(params, batch["tokens"], cfg=cfg)
-    return L.cross_entropy_chunked(hidden, params["embed"], batch["labels"])
+    return L.cross_entropy_chunked(hidden, _head(params, cfg), batch["labels"])
 
 
 def init_caches(cfg: ArchConfig, batch: int, max_len: int, n_chunks: int,
@@ -243,15 +288,15 @@ def _run_with_cache(params, x, caches, cfg: ArchConfig, dtype):
 
 def prefill(params, batch, caches, *, cfg: ArchConfig):
     dtype = jnp.dtype(cfg.act_dtype)
-    x = shard(L.embed(params["embed"], batch["tokens"], dtype), "act_btd")
+    x = _embed(params, batch["tokens"], cfg, dtype)
     hidden, caches = _run_with_cache(params, x, caches, cfg, dtype)
-    lg = L.unembed(params["embed"], hidden[:, -1:])
+    lg = L.unembed(_head(params, cfg), hidden[:, -1:])
     return lg[:, 0], caches
 
 
 def decode_step(params, caches, batch, *, cfg: ArchConfig):
     dtype = jnp.dtype(cfg.act_dtype)
-    x = L.embed(params["embed"], batch["token"], dtype)
+    x = _embed(params, batch["token"], cfg, dtype)
     hidden, caches = _run_with_cache(params, x, caches, cfg, dtype)
-    lg = L.unembed(params["embed"], hidden)
+    lg = L.unembed(_head(params, cfg), hidden)
     return lg[:, 0], caches
