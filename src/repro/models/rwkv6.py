@@ -12,18 +12,24 @@ gate silu(x^g Wg) before the output projection. The model puts a LayerNorm
 (``ln0``) on the embedding before the first block and reads its logits from
 an untied head unless ``tie_embeddings``.
 
-Training uses a chunked scan: the intra-chunk pairwise decay tensor is
-computed exactly in log-space (exp(L_{t-1}-L_j) <= 1 for j < t, so no
-overflow). Each chunk is rematerialised in the backward pass, so the scan
-keeps one [B,H,K,V] state per chunk and recomputes the chunk's [B,H,C,C,K]
-decay tensor, instead of keeping it for every chunk of every layer. Decode is
-the O(1)-state recurrence => long_500k serve_step is sub-quadratic.
+Training runs the WKV chunk-parallel, in chunks of 16 tokens laid out
+[N,B,H,C,K]. Everything that does not read the carried state runs for all
+chunks at once: the chunk's cumulative log decay L, the intra-chunk
+attention with its pairwise decays exp(L_{t-1} - L_j), exact in log space
+(the exponent is <= 0 for j < t, so nothing overflows), its product with v
+and the current token's bonus. Its backward (a custom VJP) recomputes the
+pairwise decays inside each reduction that reads them, so no [C,C,K] tensor
+is kept for all chunks. Only the [B,H,K,V] state passes from chunk to
+chunk, in a scan whose step reads it for the chunk's inter-chunk output and
+adds the chunk's own k (x) v. Decode is the O(1)-state recurrence =>
+long_500k serve_step is sub-quadratic.
 
 Departure from the published model: a log decay below ``LOG_DECAY_FLOOR``
 (-20) is taken as -20, i.e. a decay below e^-20 ~ 2e-9 is taken as e^-20.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -91,60 +97,121 @@ def _shift(x, last):
     return jnp.concatenate([last[:, None].astype(x.dtype), x[:, :-1]], axis=1)
 
 
+@partial(jax.jit, static_argnames="axis")
+def _dot(a, b, axis: int):
+    """sum(a * b) over ``axis``, a and b broadcast against each other. A jit
+    of its own: the computation that adds a reduction's terms, and its
+    transpose's, carry the op_name of the innermost traced function alone,
+    so inside this jit they do not name the ``wkv`` scope without the
+    stage around it."""
+    return jnp.sum(a * b, axis=axis)
+
+
+def _cumsum(x, axis: int):
+    """Inclusive sum along ``axis`` (a chunk's tokens), as one reduction
+    over a [j <= t] mask."""
+    C = x.shape[axis]
+    shape = [1] * (x.ndim + 1)
+    shape[axis] = shape[axis + 1] = C
+    upper = np.triu(np.ones((C, C), np.float32)).reshape(shape)  # [j, t]
+    return _dot(jnp.expand_dims(x, axis + 1), upper, axis=axis)
+
+
+def _decay(D, lower):
+    """exp(D) where ``lower`` is 1 (j < t), else 0. D <= 0 where it is
+    kept; where it is not, exp(min(D, 0)) is at most 1 and ``lower`` 0."""
+    return jnp.exp(jnp.minimum(D, 0.0)) * lower
+
+
+def _lower(C: int):
+    return np.tril(np.ones((C, C), np.float32), k=-1)    # [t, j]: j < t
+
+
+@jax.custom_vjp
+def _intra(r, k, Lp, Lc):
+    """A[t,j] = sum_k r_t k_j exp(L_{t-1} - L_j) for j < t, else 0, for
+    every chunk at once. r, k, Lp (L_{t-1}) and Lc (L_t): [N,B,H,C,K]; A:
+    [N,B,H,C,C]. The pairwise tensor [N,B,H,C,C,K] exists only inside the
+    reduction over K."""
+    with jax.named_scope("wkv"):
+        lower = _lower(Lp.shape[3])[:, :, None]             # [t, j, 1]
+        E = _decay(Lp[:, :, :, :, None] - Lc[:, :, :, None], lower)
+        return _dot(r[:, :, :, :, None] * k[:, :, :, None], E, axis=5)
+
+
+def _intra_fwd(r, k, Lp, Lc):
+    return _intra(r, k, Lp, Lc), (r, k, Lp, Lc)
+
+
+def _intra_bwd(res, dA):
+    """dr[t] = sum_j dA[t,j] k_j E[t,j] and dk[j] = sum_t dA[t,j] r_t E[t,j];
+    the log decays' cotangents follow, dLp = r dr and dLc = -k dk. Each sum
+    recomputes E in an arrangement of its own (the summed axis first of the
+    pair), so XLA fuses the exponentials into it and no pairwise tensor is
+    kept."""
+    with jax.named_scope("wkv"):
+        r, k, Lp, Lc = res
+        lower = _lower(Lp.shape[3])
+        # [N,B,H,j,t,K], summed over j
+        dr = _dot(dA.swapaxes(3, 4)[..., None] * k[:, :, :, :, None],
+                  _decay(Lp[:, :, :, None] - Lc[:, :, :, :, None],
+                         lower.T[:, :, None]), axis=3)
+        # [N,B,H,t,j,K], summed over t
+        dk = _dot(dA[..., None] * r[:, :, :, :, None],
+                  _decay(Lp[:, :, :, :, None] - Lc[:, :, :, None],
+                         lower[:, :, None]), axis=3)
+        return dr, dk, r * dr, -k * dk
+
+
+_intra.defvjp(_intra_fwd, _intra_bwd)
+
+
+@partial(jax.jit, static_argnames="chunk")
+def _chunk_parts(r, k, v, lw, u, chunk: int):
+    """What does not read the carried state, for every chunk at once, all
+    [N,B,H,C,K]: the intra-chunk output with the current token's bonus
+    (r_t . (u * k_t)) v_t, and the inputs of the state's scan,
+    q_t = r_t exp(L_{t-1}), the chunk's own kw_j = k_j exp(L_C - L_j) and v,
+    with its decay exp(L_C) [N,B,H,K]."""
+    Bsz, S, H, K = r.shape
+    nch = S // chunk
+    rc, kc, vc, lwc = (a.reshape(Bsz, nch, chunk, H, K).transpose(1, 0, 3, 2, 4)
+                       .astype(jnp.float32) for a in (r, k, v, lw))
+    Lc = _cumsum(lwc, 3)                                   # inclusive L_t
+    Lp = Lc - lwc                                          # L_{t-1}
+    LC = Lc[:, :, :, -1:]                                  # the chunk's own
+    A = _intra(rc, kc, Lp, Lc)                             # [N,B,H,t,j]
+    bonus = _dot(rc * kc, u[None, None, :, None], axis=4)  # [N,B,H,t]
+    y = jnp.einsum("nbhtj,nbhjv->nbhtv", A, vc) + bonus[..., None] * vc
+    return (y, rc * jnp.exp(Lp), kc * jnp.exp(LC - Lc), vc,
+            jnp.exp(LC[:, :, :, 0]))
+
+
 def wkv_chunked(r, k, v, lw, u, s0, chunk: int = 16):
     """r,k,v: [B,S,H,K]; lw: [B,S,H,K] log decays (<=0); u: [H,K];
-    s0: [B,H,K,V]. Returns (y [B,S,H,K], s_final)."""
+    s0: [B,H,K,V]. Returns (y [B,S,H,K], s_final), float32.
+
+    Chunk-parallel: ``_chunk_parts`` runs what does not read the carried
+    state for every chunk at once; only the [B,H,K,V] state then passes
+    from chunk to chunk, in a scan whose step reads it for the chunk's
+    y_t += (r_t exp(L_{t-1})) s and adds the chunk's own
+    sum_j exp(L_C - L_j) k_j (x) v_j to its decayed self."""
     Bsz, S, H, K = r.shape
     nch = -(-S // chunk)
     pad = nch * chunk - S
     if pad:
         z4 = ((0, 0), (0, pad), (0, 0), (0, 0))
         r, k, v, lw = (jnp.pad(a, z4) for a in (r, k, v, lw))
-    resh = lambda a: a.reshape(Bsz, nch, chunk, H, K).transpose(1, 0, 3, 2, 4)
-    rc, kc, vc, lwc = resh(r), resh(k), resh(v), resh(lw)  # [nch,B,H,C,K]
+    y, q, kw, vc, a = _chunk_parts(r, k, v, lw, u, chunk=chunk)
 
-    mask_lt = np.tril(np.ones((chunk, chunk), bool), k=-1)  # strict j < t
+    def step(s, xs):
+        q_c, kw_c, v_c, a_c = xs
+        return (a_c[..., None] * s
+                + jnp.einsum("bhck,bhcv->bhkv", kw_c, v_c),
+                jnp.einsum("bhck,bhkv->bhcv", q_c, s))
 
-    def body(s, xs):
-        rr, kk, vv, ww = xs                      # [B,H,C,K]
-        Lc = jnp.cumsum(ww, axis=2)              # inclusive [B,H,C,K]
-        # inter: y_t += (r_t * exp(L_{t-1})) @ s ; L_{t-1} = L_t - w_t
-        q_t = rr * jnp.exp(Lc - ww)
-        y_inter = jnp.einsum("bhck,bhkv->bhcv", q_t, s)
-        # intra (j < t): A[t,j] = sum_k r_t k_j exp(L_{t-1}-L_j)  (exp arg <= 0)
-        Dk = (Lc - ww)[:, :, :, None, :] - Lc[:, :, None, :, :]  # [B,H,C,C,K]
-        Dk = jnp.where(mask_lt[None, None, :, :, None], Dk, -jnp.inf)
-        A = jnp.einsum("bhtk,bhjk,bhtjk->bhtj", rr, kk, jnp.exp(Dk))
-        y_intra = jnp.einsum("bhtj,bhjv->bhtv", A, vv)
-        # current-token bonus: (r_t . (u * k_t)) v_t
-        bonus = jnp.einsum("bhck,bhck->bhc", rr, u[None, :, None, :] * kk)
-        y_bonus = bonus[..., None] * vv
-        # state: s' = diag(exp(L_C)) s + sum_j diag(exp(L_C - L_j)) k_j (x) v_j
-        wtail = jnp.exp(Lc[:, :, -1:, :] - Lc)   # [B,H,C,K]
-        s_new = (jnp.exp(Lc[:, :, -1, :])[..., None] * s
-                 + jnp.einsum("bhjk,bhjv->bhkv", kk * wtail, vv))
-        return s_new, y_inter + y_intra + y_bonus
-
-    from .unroll_ctx import active as _unroll_active
-    if _unroll_active():
-        # COST-PROBE PATH (dry-run only): vmap the chunk bodies with a dummy
-        # state. Operation count per chunk is identical to the sequential
-        # scan; OUTPUT VALUES ARE WRONG (state not propagated). Never taken
-        # outside launch/dryrun.py probes.
-        _, ys = jax.vmap(body, in_axes=(None, 0))(
-            s0.astype(jnp.float32),
-            (rc.astype(jnp.float32), kc.astype(jnp.float32),
-             vc.astype(jnp.float32), lwc))
-        s_fin = s0.astype(jnp.float32)
-    else:
-        # rematerialised: the backward keeps each chunk's carried state and
-        # inputs, and recomputes its [B,H,C,C,K] decay tensor
-        s_fin, ys = jax.lax.scan(jax.checkpoint(body, prevent_cse=False),
-                                 s0.astype(jnp.float32),
-                                 (rc.astype(jnp.float32),
-                                  kc.astype(jnp.float32),
-                                  vc.astype(jnp.float32), lwc))
-    y = ys.transpose(1, 0, 3, 2, 4).reshape(Bsz, nch * chunk, H, K)
+    s_fin, y_inter = uscan(step, s0.astype(jnp.float32), (q, kw, vc, a))
+    y = (y + y_inter).transpose(1, 0, 3, 2, 4).reshape(Bsz, nch * chunk, H, K)
     return y[:, :S], s_fin
 
 

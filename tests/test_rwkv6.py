@@ -1,6 +1,8 @@
 """RWKV6 Finch in the model zoo: the chunked WKV against the token recurrence
-(values and gradients), and prefill then decode through ``RwkvCache``
-against the full forward pass's logits."""
+(values and gradients) and the ``wkv`` scope of its backward, and prefill
+then decode through ``RwkvCache`` against the full forward pass's logits."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,8 +43,9 @@ def _inputs(S, decay):
     return r, k, v, lw, u, s0
 
 
-# S a multiple of the chunk (16) and not; decays moderate and at the floor
-@pytest.mark.parametrize("S", [48, 37])
+# S a multiple of the chunk (16) and not, over a few chunks and over many
+# (300: 19 chunks, the last cut short); decays moderate and at the floor
+@pytest.mark.parametrize("S", [48, 37, 300])
 @pytest.mark.parametrize("decay", ["moderate", "near_floor"])
 def test_wkv_chunked_matches_the_recurrence(S, decay):
     args = _inputs(S, decay)
@@ -71,6 +74,39 @@ def test_wkv_chunked_matches_the_recurrence(S, decay):
     for name, a, b in zip(("r", "k", "v", "lw", "u", "s0"), g, g_ref):
         np.testing.assert_allclose(a / scale, b / scale, rtol=0, atol=3e-5,
                                    err_msg=name)
+
+
+def _in_scope(op_name: str, scope: str) -> bool:
+    """``scope`` is a component of the op_name's path, a transform's
+    wrapper (``transpose(jvp(...))``) taken off."""
+    for part in op_name.split(";", 1)[0].split("/")[:-1]:
+        while (m := re.fullmatch(r"[\w.<>]*\((.*)\)", part)):
+            part = m.group(1)
+        if part == scope:
+            return True
+    return False
+
+
+def test_the_wkv_backward_keeps_its_scope():
+    """The gradient of one time-mix block, compiled: the WKV's backward ops
+    (op_names under ``transpose(``), its loop over the chunks and the
+    exponentials of the decays it recomputes, carry ``wkv`` in their path,
+    so that a reading of the scope counts the backward as well."""
+    cfg = get_bundle("rwkv6-1.6b", reduced=True, act_dtype="float32",
+                     d_ff=448).cfg
+    p = rwkv6.init_block(jax.random.fold_in(KEY, 3), cfg)
+    x = jax.random.normal(jax.random.fold_in(KEY, 4), (2, 37, cfg.d_model))
+
+    def f(p, x):
+        return jnp.sum(rwkv6.time_mix(p, x, cfg, jnp.float32, None)[0])
+
+    text = jax.jit(jax.grad(f)).lower(p, x).compile().as_text()
+    backward = {n for n in re.findall(r'op_name="([^"]*)"', text)
+                if "transpose(" in n}
+    wkv = [n for n in backward
+           if "/while" in n or n.split(";", 1)[0].endswith("/exp")]
+    assert any("/while" in n for n in wkv) and any("exp" in n for n in wkv)
+    assert all(_in_scope(n, "wkv") for n in wkv), wkv
 
 
 def test_groupnorm_normalises_each_group():

@@ -195,12 +195,55 @@ def test_subset_diameters_compile(one_chip, n, f):
         d2, masks)
 
 
+def _hlo_computations(text):
+    """The instruction lines of each computation of a compiled module's
+    text, by computation name."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            cur = None
+            if line.endswith("{"):
+                cur = line.split(" ", 2)[
+                    1 if line.startswith("ENTRY ") else 0].lstrip("%")
+                comps[cur] = []
+        elif cur is not None and " = " in line:
+            comps[cur].append(line)
+    return comps
+
+
+def _result_dims(line):
+    """The dimensions of each array in an instruction's result."""
+    result = re.match(r"\s*(?:ROOT )?\S+ = (.*?) [\w-]+\(", line).group(1)
+    return [tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result)]
+
+
+def _called(comps, roots):
+    """The computations ``roots`` run, themselves included."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                    line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
 def test_finch_wkv_backward_keeps_one_state_per_chunk(one_chip):
     """One Finch time-mix layer at the rwkv6-g4-s2k cell's widths (D 2048,
-    32 heads of 64, 2048 tokens, G=4 replicas), forward and backward: the
-    chunked WKV's backward keeps one [H,K,V] state per chunk and recomputes
-    each chunk's [C,C,K] decay tensor, so no buffer holds that tensor for
-    all 128 chunks (1.07 GB at these shapes, twice that padded)."""
+    32 heads of 64, 2048 tokens, G=4 replicas), forward and backward. The
+    chunk-parallel WKV computes every chunk's [C,C,K] pairwise decays inside
+    the reductions that read them, forward and backward, so no buffer
+    outside a fusion holds that tensor for all 128 chunks (1.07 GB at these
+    shapes, twice that padded); and its loop over the chunks holds no
+    pairwise op ([..,16,16,..] or [..,16,64,16]), only the [H,K,V] state
+    and each chunk's own rows."""
     from repro.models import rwkv6
     from repro.models.registry import get_bundle
     cfg = get_bundle("rwkv6-1.6b", n_layers=1, vocab=8192).cfg
@@ -216,9 +259,21 @@ def test_finch_wkv_backward_keeps_one_state_per_chunk(one_chip):
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss)).lower(blk, x).compile()
-    text = compiled.as_text()
-    chunks = S // 16
-    per_chunk = re.compile(rf"\[{chunks},[\d,]*(16,16,64|16,64,16)\]")
-    assert "while" in text and not per_chunk.search(text)
-    # 1.85 GiB of temporaries with the chunk rematerialised, 6.0 without
+    comps = _hlo_computations(compiled.as_text())
+    chunks, chunk = S // 16, 16
+    fused = {c for lines in comps.values() for line in lines
+             for c in re.findall(r"calls=%([\w.\-]+)", line)}
+    for c in set(comps) - fused:
+        for line in comps[c]:
+            for dims in _result_dims(line):
+                assert not (dims.count(chunk) >= 2 and chunks in dims
+                            and 64 in dims), line
+    bodies = {b for lines in comps.values() for line in lines
+              for b in re.findall(r"body=%([\w.\-]+)", line)}
+    assert bodies
+    pairwise = re.compile(r"(^|,)(16,16|16,64,16)(,|$)")
+    for c in _called(comps, bodies):
+        for line in comps[c]:
+            for dims in _result_dims(line):
+                assert not pairwise.search(",".join(map(str, dims))), line
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
