@@ -16,10 +16,6 @@ gradient to server (w + k) % n_ps only, which replies with its model —
 neither direction is a broadcast (the worker_tx n_ps·d -> 1·d correction
 flagged in ROADMAP; repro.netsim counts the same schedule, and exp_netsim's
 wallclock section logs the deviation vs the old broadcast accounting).
-
-Also cross-checked against the *measured* per-device collective bytes of the
-compiled distributed protocol (results/dryrun), which uses all-gathers instead
-of point-to-point sends.
 """
 from __future__ import annotations
 

@@ -8,8 +8,7 @@
     python -m benchmarks.run --exp smoke --store             # sweep cache
 
 Figure lanes run one experiment per paper figure/claim (reduced sizes by
-default; --full runs paper-scale step counts) plus the roofline table from
-the dry-run artifacts when present. ``--exp`` runs a ``repro.exp`` preset
+default; --full runs paper-scale step counts). ``--exp`` runs a ``repro.exp`` preset
 (with ``--override key=val`` field overrides) through one or more runners and
 writes each RunResult verbatim. Every result JSON carries a ``provenance``
 block (spec hash, git sha, jax version, device). ``--store`` additionally
@@ -147,15 +146,6 @@ def main():
         with open(os.path.join(args.out, f"{name}.json"), "w") as f:
             json.dump(res, f, indent=1, default=float)
 
-    # roofline table from the dry-run artifacts; cells the dry-run has not
-    # written come back as "missing" rows, any other failure surfaces
-    from repro.launch import roofline
-    rows = roofline.full_table()
-    if any("skipped" not in r for r in rows):
-        print("[roofline] single-pod baseline (naive engine):")
-        print(roofline.format_table(rows))
-    else:
-        print(f"[roofline] no dry-run artifacts under {roofline.RESULTS_DIR}")
     print(f"\ntotal {time.time()-t00:.1f}s")
 
     if baseline is not None:

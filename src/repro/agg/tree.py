@@ -76,7 +76,6 @@ def _reduce_stream(fn, leaf, chunk_bytes: int):
     """Accumulate fn(chunk) over slices of a large leaf: dim-1 for layer
     stacks, last dim for wide tables (mirrors the protocol's exchange
     streaming — bounds per-chunk transients without a full-leaf copy)."""
-    from ..models import unroll_ctx
     big = leaf.size * leaf.dtype.itemsize > chunk_bytes
     n = leaf.shape[0]
     if leaf.ndim < 3 or not big:
@@ -93,9 +92,6 @@ def _reduce_stream(fn, leaf, chunk_bytes: int):
     def chunk_at(i):
         sl = jax.lax.dynamic_slice_in_dim(leaf, i * csize, csize, axis=ax)
         return jnp.squeeze(sl, 1) if (ax == 1 and csize == 1) else sl
-
-    if unroll_ctx.active():
-        return sum(fn(chunk_at(i)) for i in range(n_steps))
 
     def body(i, acc):
         return acc + fn(chunk_at(i))

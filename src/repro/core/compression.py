@@ -12,8 +12,8 @@ Provided operators (pytree-aware, jit-able):
   * sign_compress     — sign(g) * mean|g| per leaf
 Each returns a same-structure pytree (dense representation with zeros — the
 wire format on a real deployment would be (indices, values); the dense form
-keeps the protocol path unchanged and lets the dry-run measure byte ratios
-via the exchange dtype).
+keeps the protocol path unchanged; byte ratios are read from the exchange
+dtype).
 """
 from __future__ import annotations
 

@@ -18,8 +18,8 @@ Asynchrony = per-step delivery quorums: every step builder takes a pluggable
 default, with the *same* PRNG chain as the single-host simulator so the
 1-device protocol is oracle-checked against it) or a netsim ``TraceDelivery``
 replaying realized quorums. Byzantine behaviour is injected for
-tests/benchmarks and *excluded from roofline lowers* (a real adversary costs
-nothing extra on the honest path).
+tests/benchmarks and *excluded from the benchmark cells' programs* (a real
+adversary costs nothing extra on the honest path).
 
 Engines:
   * 'naive'   — baseline, paper-faithful collective volume: gradients/replicas
@@ -342,10 +342,8 @@ def _map_dim1(fn, *leaves, mesh=None):
     leaf triggers XLA SPMD "involuntary full rematerialization" = per-device
     replication of the whole stack). The loop-carried accumulator is
     explicitly constrained to the replica-stacked layout (otherwise XLA
-    replicates it). Under the dry-run unroll context this becomes a python
-    loop so cost_analysis counts every iteration.
+    replicates it).
     """
-    from ..models import unroll_ctx
     L = leaves[0].shape[1]
 
     def slice_at(i):
@@ -354,9 +352,6 @@ def _map_dim1(fn, *leaves, mesh=None):
 
     out0 = jax.eval_shape(fn, *(jax.eval_shape(lambda l: jnp.squeeze(l[:, :1], 1), l)
                                 for l in leaves))
-    if unroll_ctx.active():
-        chunks = [fn(*slice_at(i)) for i in range(L)]
-        return jnp.stack(chunks, axis=1)
 
     def body(i, acc):
         res = fn(*slice_at(i))
@@ -379,7 +374,6 @@ def _map_last_chunks(fn, *leaves, n_chunks: int, mesh=None):
     """Chunked streaming over the LAST (unsharded) dim — used for wide tables
     (embeddings: [G, V('model'), D]); slicing the sharded V dim would localise
     each chunk to a single device, so we slice D instead."""
-    from ..models import unroll_ctx
     ax = leaves[0].ndim - 1
     D = leaves[0].shape[ax]
     csize = D // n_chunks
@@ -391,9 +385,6 @@ def _map_last_chunks(fn, *leaves, n_chunks: int, mesh=None):
     out0 = jax.eval_shape(fn, *(jax.eval_shape(
         lambda l: jax.lax.slice_in_dim(l, 0, csize, axis=ax), l)
         for l in leaves))
-    if unroll_ctx.active():
-        return jnp.concatenate([fn(*slice_at(i)) for i in range(n_chunks)],
-                               axis=ax)
 
     def body(i, acc):
         res = fn(*slice_at(i))
@@ -663,25 +654,16 @@ def make_scatter_step(bundle, pcfg: ProtocolConfig, lr_schedule,
             gfn = jax.vmap(jax.grad(bundle.loss),
                            spmd_axis_name="rep" if mesh is not None else None)
             if pcfg.grad_microbatches > 1:
-                from ..models import unroll_ctx as _uctx
+                def micro_body(acc, mb):
+                    g = gfn(pulled, mb)
+                    return jax.tree.map(
+                        lambda a, x: a + x.astype(jnp.float32)
+                        / pcfg.grad_microbatches, acc, g), None
 
-                # cost-probe: vmap micro-steps (flop-identical)
-                if _uctx.active():
-                    gm = jax.vmap(gfn, in_axes=(None, 0))(pulled, batch)
-                    grads = jax.tree.map(
-                        lambda x: jnp.mean(x.astype(jnp.float32), axis=0), gm)
-                else:
-                    def micro_body(acc, mb):
-                        g = gfn(pulled, mb)
-                        return jax.tree.map(
-                            lambda a, x: a + x.astype(jnp.float32)
-                            / pcfg.grad_microbatches, acc, g), None
-
-                    zeros = jax.tree.map(
-                        lambda p: jnp.zeros(p.shape, jnp.float32),
-                        state.params)
-                    zeros = _constrain_like_params(zeros)
-                    grads, _ = jax.lax.scan(micro_body, zeros, batch)
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+                zeros = _constrain_like_params(zeros)
+                grads, _ = jax.lax.scan(micro_body, zeros, batch)
             else:
                 grads = gfn(pulled, batch)
             grads = jax.tree.map(

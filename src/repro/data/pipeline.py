@@ -11,8 +11,7 @@ EXPERIMENTS.md §Repro). Properties preserved:
   (Appendix D) depend on this and reproduce cleanly.
 
 For the LM architectures, ``token_stream`` yields deterministic pseudo-random
-token batches (the dry-run itself only needs ShapeDtypeStructs; tokens are for
-smoke tests and the end-to-end examples).
+token batches for smoke tests and the end-to-end examples.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Post-SPMD HLO analysis: collective traffic + cost extrapolation.
+"""Post-SPMD HLO analysis: collective traffic + donation auditing.
 
 Collective bytes use a ring model on the *per-device* (post-partitioning)
 shapes that appear in ``compiled.as_text()``:
@@ -12,13 +12,6 @@ shapes that appear in ``compiled.as_text()``:
 (g = replica-group size). Summing per-device traffic and dividing by the
 per-chip link bandwidth is algebraically the spec's
 ``collective_bytes / (chips * link_bw)`` with collective_bytes = total traffic.
-
-XLA's cost_analysis does NOT scale loop bodies by trip count (verified
-empirically), so per-layer costs come from two depth probes:
-    per_layer = (cost(L2) - cost(L1)) / (L2 - L1)
-    total(L)  = cost(L1) + per_layer * (L - L1)
-— exact for homogeneous layer stacks (all 10 archs; the zamba2 leftover
-segment makes this an upper bound within <1%, see DESIGN.md).
 """
 from __future__ import annotations
 
@@ -114,12 +107,6 @@ def collective_traffic(hlo_text: str, n_devices: int) -> CollectiveStats:
             b = float(op_b)
         stats.add(kind, b, g)
     return stats
-
-
-def extrapolate(v1: float, v2: float, l1: int, l2: int, total: int) -> float:
-    """Two-point linear depth extrapolation."""
-    per_layer = (v2 - v1) / max(l2 - l1, 1)
-    return v1 + per_layer * (total - l1)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,7 @@
-"""Production mesh builders.
+"""Mesh builders. Every builder is a FUNCTION (never a module-level constant)
+so importing this module never touches jax device state.
 
-``make_production_mesh`` is the spec-mandated entry: single-pod 16x16
-('data','model') or multi-pod 2x16x16 ('pod','data','model'). It is a FUNCTION
-(never a module-level constant) so importing this module never touches jax
-device state.
-
+``make_mesh`` builds a ('data', 'model') or ('pod', 'data', 'model') mesh;
 ``make_byz_mesh`` derives the ByzSGD training view over the *same* devices:
 ('rep', 'fsdp', 'model') where 'rep' indexes the n_groups co-located
 worker/server groups (failure domains — DESIGN.md §Worker granularity) and
@@ -35,12 +32,6 @@ def _mk_mesh(devs, names):
     return Mesh(devs, names, axis_types=(_AUTO,) * len(names))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def dp_size(mesh) -> int:
     """Total data-parallel slices R (pod x data)."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -52,7 +43,8 @@ def model_size(mesh) -> int:
 
 
 def make_byz_mesh(mesh, n_groups: int) -> Mesh:
-    """('rep', 'fsdp', 'model') view over the production mesh's devices."""
+    """('rep', 'fsdp', 'model') view over a ('data', 'model') or
+    ('pod', 'data', 'model') mesh's devices."""
     R, M = dp_size(mesh), model_size(mesh)
     if R % n_groups:
         raise ValueError(f"n_groups={n_groups} must divide dp slices R={R}")
@@ -66,7 +58,7 @@ def make_protocol_mesh(n_groups: int, devices=None, *,
     """('rep', 'fsdp', 'model') mesh over the *available* devices for a
     G-group protocol run (the ``Experiment.runner="protocol"`` path).
 
-    Unlike :func:`make_byz_mesh` (which carves a production mesh whose dp
+    Unlike :func:`make_byz_mesh` (which carves a given mesh whose dp
     slices must divide into the groups), this places 'rep' on the largest
     divisor of ``n_groups`` that the device count can host — down to a
     1-device (1,1,1) mesh, where all G replica stacks live on one chip and the

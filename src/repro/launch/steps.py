@@ -1,24 +1,13 @@
-"""Step builders: (arch x shape x mesh) -> jit-able fn + fully-specified specs.
+"""Activation sharding rules: logical name -> NamedSharding for the
+model-internal ``shard`` constraints (``models/sharding.py``).
 
-Used by launch/dryrun.py (ShapeDtypeStruct lowering — no allocation) and by
-launch/train.py / launch/serve.py (real execution). All sharding decisions live
-here and in core/protocol.py.
+``train_rules`` serves the ByzSGD train mesh ('rep', 'fsdp', 'model') — the
+benchmark program, ``repro.exp``'s protocol runner and launch/train.py install
+it; ``serve_rules`` the flat ('data', 'model') serving mesh of launch/serve.py.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any, Callable
-
-import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ..configs.shapes import ShapeCell
-from ..core import protocol
-from ..models import sharding as shrules
-from ..models.registry import ModelBundle, get_bundle
-from ..optim.schedules import inverse_linear
-from . import mesh as meshlib
 
 
 # ---------------------------------------------------------------------------
@@ -39,14 +28,7 @@ def train_rules(bmesh, cfg):
     # divisible by 16 for all 10 archs): the per-layer remat-saved scan
     # carries shrink 16x, and the qkv/ffn input projections contract the
     # sharded dim (partial matmul + reduce) without layout churn.
-    # REPRO_RESID_REPLICATED=1 keeps the residual replicated over 'model'
-    # instead (-10% collective bytes on the hillclimbed cell, +16x carry
-    # memory — affordable post-micro-batching; §Perf iteration 12).
-    import os as _os
-    if _os.environ.get("REPRO_RESID_REPLICATED") == "1":
-        r["act_btd"] = ns("fsdp", None, None)
-    else:
-        r["act_btd"] = ns("fsdp", None, "model")
+    r["act_btd"] = ns("fsdp", None, "model")
     r["logits"] = ns("fsdp", None, "model")
     if cfg.n_heads % M == 0:
         r["act_heads"] = ns("fsdp", None, "model", None)
@@ -81,286 +63,3 @@ def serve_rules(smesh, cfg):
         r["expert_tokens"] = ns(None, "data", None)  # capacity over 'data'
     r["kv_cache"] = ns("data", None, "model", None, None)
     return r
-
-
-# ---------------------------------------------------------------------------
-# serving param / cache specs
-# ---------------------------------------------------------------------------
-
-def serve_param_sharding(shapes_tree, smesh, cfg):
-    """Consolidated-model sharding for serving: 'model' on TP dims; big models
-    additionally ZeRO-shard over 'data' (per-layer gather at use)."""
-    sizes = dict(zip(smesh.axis_names, smesh.devices.shape))
-    M, Dax = sizes["model"], sizes["data"]
-    total_bytes = sum(l.size * jnp.dtype(l.dtype).itemsize
-                      for l in jax.tree.leaves(shapes_tree))
-    shard_data = (total_bytes / M) > 4 * 2**30  # >4GB/chip after TP -> ZeRO
-
-    def one(leaf):
-        if leaf.ndim == 0 or leaf.size <= 2:
-            return NamedSharding(smesh, P())
-        body = list(leaf.shape)
-        spec: list = [None] * len(body)
-        order = sorted(range(len(body)), key=lambda i: -body[i])
-        m_at = next((i for i in order if body[i] % M == 0 and body[i] >= M), None)
-        if m_at is not None:
-            spec[m_at] = "model"
-        if shard_data:
-            d_at = next((i for i in order
-                         if i != m_at and body[i] % Dax == 0 and body[i] >= Dax),
-                        None)
-            if d_at is not None:
-                spec[d_at] = "data"
-        return NamedSharding(smesh, P(*spec))
-
-    return jax.tree.map(one, shapes_tree)
-
-
-def cache_sharding(cache_shapes, smesh):
-    """KV caches: batch over 'data', chunk axis over 'model' (flash-decode).
-    SSM/conv states: batch over 'data', largest divisible dim over 'model'."""
-    sizes = dict(zip(smesh.axis_names, smesh.devices.shape))
-    M, Dax = sizes["model"], sizes["data"]
-
-    def one(leaf):
-        if leaf.ndim == 0:
-            return NamedSharding(smesh, P())
-        body = list(leaf.shape)
-        spec: list = [None] * len(body)
-        if leaf.ndim == 6:  # stacked KVCache k/v: [L, B, kvH, nc, chunk, hd]
-            if body[1] % Dax == 0 and body[1] >= Dax:
-                spec[1] = "data"
-            if body[3] % M == 0 and body[3] >= M:
-                spec[3] = "model"
-            return NamedSharding(smesh, P(*spec))
-        if leaf.ndim == 1:  # lengths [L]
-            return NamedSharding(smesh, P())
-        # generic state: dim1 = batch -> data; largest other -> model
-        if len(body) > 1 and body[1] % Dax == 0 and body[1] >= Dax:
-            spec[1] = "data"
-        order = sorted(range(len(body)), key=lambda i: -body[i])
-        m_at = next((i for i in order
-                     if spec[i] is None and i != 0 and body[i] % M == 0
-                     and body[i] >= M), None)
-        if m_at is not None:
-            spec[m_at] = "model"
-        return NamedSharding(smesh, P(*spec))
-
-    return jax.tree.map(one, cache_shapes)
-
-
-def _batch_sharding(name, sds, smesh):
-    """'data' on the batch dim when divisible (long_500k B=1 stays replicated —
-    a single-replica workload, noted in the roofline)."""
-    Dax = dict(zip(smesh.axis_names, smesh.devices.shape))["data"]
-    bdim = 1 if name == "positions" else 0
-    spec = [None] * len(sds.shape)
-    if sds.shape[bdim] % Dax == 0 and sds.shape[bdim] >= Dax:
-        spec[bdim] = "data"
-    return NamedSharding(smesh, P(*spec))
-
-
-def _with_sharding(sds_tree, sharding_tree):
-    return jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        sds_tree, sharding_tree)
-
-
-# ---------------------------------------------------------------------------
-# cell builders
-# ---------------------------------------------------------------------------
-
-@dataclass
-class BuiltCell:
-    fn: Callable               # jit-able step function
-    in_specs: tuple            # ShapeDtypeStructs (with shardings) for .lower()
-    mesh: Any                  # mesh to enter while lowering
-    rules: dict                # activation sharding rules context
-    meta: dict
-
-
-def build_train_cell(arch: str, cell: ShapeCell, prod_mesh, *,
-                     engine: str = "naive", exchange_dtype: str = "float32",
-                     reduced: bool = False, T: int = 50, depth: int | None = None,
-                     pull: str = "median",
-                     include_gather: bool = False) -> BuiltCell:
-    bundle = get_bundle(arch, reduced=reduced, depth=depth)
-    cfg = bundle.cfg
-    R = meshlib.dp_size(prod_mesh)
-    G0 = R // cfg.byz_group_divisor
-    if cfg.byz_group_cap:
-        G0 = min(G0, cfg.byz_group_cap)
-    B, S = cell.global_batch, cell.seq_len
-    # micro-batching: bound per-worker tokens per fwd/bwd at ~16k
-    per_group = B // G0
-    K = R // G0  # fsdp axis size — the micro-batch must stay K-shardable
-    n_micro = max(1, min(per_group, (per_group * S) // 8192))
-    n_micro = min(n_micro, max(per_group // max(K, 1), 1))
-    while per_group % n_micro or (per_group // n_micro) % max(K, 1):
-        n_micro -= 1
-    pcfg = protocol.ProtocolConfig.derive(
-        R, R // G0, T=T, engine=engine, pull=pull,
-        exchange_dtype=exchange_dtype, grad_microbatches=n_micro)
-    bmesh = meshlib.make_byz_mesh(prod_mesh, pcfg.n_groups)
-    G = pcfg.n_groups
-    assert B % G == 0, (arch, cell.name, B, G)
-
-    init = protocol.make_init_fn(bundle, pcfg)
-    state_shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    state_shard = protocol.state_shardings(
-        state_shapes, bmesh, overrides=protocol.attn_overrides(cfg, bmesh))
-    state_sds = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        state_shapes, state_shard)
-
-    batch_specs = bundle.batch_specs("train", B, S)
-    nm = pcfg.grad_microbatches
-
-    def group_split(sds):
-        b_m = sds.shape[0] // G // nm
-        shape = (G, b_m) + sds.shape[1:]
-        spec = ("rep", "fsdp") + (None,) * (len(sds.shape) - 1)
-        if nm > 1:
-            shape = (nm,) + shape
-            spec = (None,) + spec
-        return jax.ShapeDtypeStruct(shape, sds.dtype,
-                                    sharding=NamedSharding(bmesh, P(*spec)))
-
-    # leading-dim exception: vlm positions [3, B, S] -> [(nm,) 3, G, b, S]
-    def split_one(name, sds):
-        if name == "positions" and sds.shape[0] == 3:
-            b_m = sds.shape[1] // G // nm
-            shape = (3, G, b_m) + sds.shape[2:]
-            spec = (None, "rep", "fsdp") + (None,) * (len(sds.shape) - 2)
-            if nm > 1:
-                shape = (nm,) + shape
-                spec = (None,) + spec
-            return jax.ShapeDtypeStruct(shape, sds.dtype,
-                                        sharding=NamedSharding(bmesh, P(*spec)))
-        return group_split(sds)
-
-    gbatch = {k: split_one(k, v) for k, v in batch_specs.items()}
-
-    rules = train_rules(bmesh, cfg)
-    if cfg.family == "vlm":
-        # batch carries positions [3, G, B/G, S]; model expects [3, b, S] per
-        # worker — handled by the wrapper below.
-        pass
-
-    step_builder = protocol.make_train_step if include_gather else \
-        protocol.make_scatter_step
-    raw_step = step_builder(bundle, pcfg, inverse_linear(0.05, 0.01), mesh=bmesh)
-
-    def step(state, batch):
-        if "positions" in batch:
-            batch = dict(batch)
-            ax = 0 if pcfg.grad_microbatches == 1 else 1
-            # [.., 3, G, b, S] -> [.., G, 3, b, S] so the worker vmap maps G
-            batch["positions"] = jnp.moveaxis(batch["positions"], ax, ax + 1)
-        with shrules.sharding_rules(rules):
-            return raw_step(state, batch)
-
-    return BuiltCell(fn=step, in_specs=(state_sds, gbatch), mesh=bmesh,
-                     rules=rules,
-                     meta={"arch": arch, "cell": cell.name, "kind": "train",
-                           "G": G, "pcfg": pcfg, "bundle": bundle})
-
-
-def build_gather_cell(arch: str, cell: ShapeCell, prod_mesh, *,
-                      engine: str = "naive", reduced: bool = False,
-                      depth: int | None = None) -> BuiltCell:
-    """DMC gather step alone (amortised 1/T in the roofline)."""
-    bundle = get_bundle(arch, reduced=reduced, depth=depth)
-    cfg = bundle.cfg
-    R = meshlib.dp_size(prod_mesh)
-    G0 = meshlib.dp_size(prod_mesh) // cfg.byz_group_divisor
-    if cfg.byz_group_cap:
-        G0 = min(G0, cfg.byz_group_cap)
-    pcfg = protocol.ProtocolConfig.derive(R, R // G0, engine=engine)
-    bmesh = meshlib.make_byz_mesh(prod_mesh, pcfg.n_groups)
-    init = protocol.make_init_fn(bundle, pcfg)
-    state_shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    state_shard = protocol.state_shardings(
-        state_shapes, bmesh, overrides=protocol.attn_overrides(cfg, bmesh))
-    state_sds = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        state_shapes, state_shard)
-    raw = protocol.make_gather_step(pcfg, mesh=bmesh)
-    return BuiltCell(fn=raw, in_specs=(state_sds,), mesh=bmesh, rules={},
-                     meta={"arch": arch, "cell": cell.name, "kind": "gather",
-                           "G": pcfg.n_groups, "pcfg": pcfg, "bundle": bundle})
-
-
-def _serve_params_specs(bundle, smesh):
-    p_shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
-    act = jnp.dtype(bundle.cfg.param_dtype)
-    p_shapes = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16), p_shapes)
-    shard = serve_param_sharding(p_shapes, smesh, bundle.cfg)
-    return _with_sharding(p_shapes, shard)
-
-
-def build_prefill_cell(arch: str, cell: ShapeCell, prod_mesh, *,
-                       reduced: bool = False, depth: int | None = None) -> BuiltCell:
-    bundle = get_bundle(arch, reduced=reduced, depth=depth)
-    cfg = bundle.cfg
-    smesh = meshlib.make_serve_mesh(prod_mesh)
-    M = meshlib.model_size(prod_mesh)
-    B, S = cell.global_batch, cell.seq_len
-    params_sds = _serve_params_specs(bundle, smesh)
-    caches_shapes = jax.eval_shape(
-        lambda: bundle.init_caches(B, max_len=S, n_chunks=M))
-    caches_sds = _with_sharding(caches_shapes, cache_sharding(caches_shapes, smesh))
-    batch = bundle.batch_specs("prefill", B, S)
-    batch_sds = {k: jax.ShapeDtypeStruct(
-        v.shape, v.dtype, sharding=_batch_sharding(k, v, smesh))
-        for k, v in batch.items()}
-    rules = serve_rules(smesh, cfg)
-
-    def fn(params, batch, caches):
-        with shrules.sharding_rules(rules):
-            return bundle.prefill(params, batch, caches)
-
-    return BuiltCell(fn=fn, in_specs=(params_sds, batch_sds, caches_sds),
-                     mesh=smesh, rules=rules,
-                     meta={"arch": arch, "cell": cell.name, "kind": "prefill",
-                           "bundle": bundle})
-
-
-def build_decode_cell(arch: str, cell: ShapeCell, prod_mesh, *,
-                      reduced: bool = False, depth: int | None = None) -> BuiltCell:
-    bundle = get_bundle(arch, reduced=reduced, depth=depth)
-    cfg = bundle.cfg
-    smesh = meshlib.make_serve_mesh(prod_mesh)
-    M = meshlib.model_size(prod_mesh)
-    B, S = cell.global_batch, cell.seq_len
-    params_sds = _serve_params_specs(bundle, smesh)
-    caches_shapes = jax.eval_shape(
-        lambda: bundle.init_caches(B, max_len=S, n_chunks=M))
-    caches_sds = _with_sharding(caches_shapes, cache_sharding(caches_shapes, smesh))
-    batch = bundle.batch_specs("decode", B, S)
-    batch_sds = {k: jax.ShapeDtypeStruct(
-        v.shape, v.dtype, sharding=_batch_sharding(k, v, smesh))
-        for k, v in batch.items()}
-    rules = serve_rules(smesh, cfg)
-
-    def fn(params, caches, batch):
-        with shrules.sharding_rules(rules):
-            return bundle.decode(params, caches, batch)
-
-    return BuiltCell(fn=fn, in_specs=(params_sds, caches_sds, batch_sds),
-                     mesh=smesh, rules=rules,
-                     meta={"arch": arch, "cell": cell.name, "kind": "decode",
-                           "bundle": bundle})
-
-
-def build_cell(arch: str, cell: ShapeCell, prod_mesh, **kw) -> BuiltCell:
-    if cell.kind == "train":
-        return build_train_cell(arch, cell, prod_mesh, **kw)
-    if cell.kind == "prefill":
-        kw.pop("engine", None); kw.pop("exchange_dtype", None); kw.pop("pull", None)
-        return build_prefill_cell(arch, cell, prod_mesh, **kw)
-    if cell.kind == "decode":
-        kw.pop("engine", None); kw.pop("exchange_dtype", None); kw.pop("pull", None)
-        return build_decode_cell(arch, cell, prod_mesh, **kw)
-    raise ValueError(cell.kind)

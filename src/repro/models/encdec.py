@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import layers as L
-from .unroll_ctx import scan as uscan
 from .config import ArchConfig
 from .sharding import shard
 
@@ -90,7 +89,7 @@ def encode(params, frames, *, cfg: ArchConfig, remat: bool = True):
     def sb(x, blk):
         return body(blk, x), None
 
-    x, _ = uscan(sb, x, params["enc_blocks"])
+    x, _ = jax.lax.scan(sb, x, params["enc_blocks"])
     return L.layernorm(params["ln_enc"], x, cfg.norm_eps)
 
 
@@ -131,7 +130,7 @@ def decode_train(params, tokens, enc_out, *, cfg: ArchConfig,
     def sb(x, blk):
         return body(blk, x), None
 
-    x, _ = uscan(sb, x, params["dec_blocks"])
+    x, _ = jax.lax.scan(sb, x, params["dec_blocks"])
     return L.layernorm(params["ln_f"], x, cfg.norm_eps)
 
 
@@ -196,7 +195,7 @@ def prefill(params, batch, caches: EncDecCaches, *, cfg: ArchConfig):
         x = x + L.gelu_mlp(blk["mlp"], h, dtype)
         return x, (kvcache, kc.astype(dtype), vc.astype(dtype))
 
-    x, (kv, ck, cv) = uscan(sb, x, (params["dec_blocks"], caches.self_kv))
+    x, (kv, ck, cv) = jax.lax.scan(sb, x, (params["dec_blocks"], caches.self_kv))
     hidden = L.layernorm(params["ln_f"], x[:, -1:], cfg.norm_eps)
     lg = L.unembed(params["embed"], hidden)
     return lg[:, 0], EncDecCaches(kv, ck, cv)
@@ -229,7 +228,7 @@ def decode_step(params, caches: EncDecCaches, batch, *, cfg: ArchConfig):
         x = x + L.gelu_mlp(blk["mlp"], h, dtype)
         return x, kvcache
 
-    x, kv = uscan(
+    x, kv = jax.lax.scan(
         sb, x, (params["dec_blocks"], caches.self_kv, caches.cross_k,
                 caches.cross_v))
     hidden = L.layernorm(params["ln_f"], x, cfg.norm_eps)

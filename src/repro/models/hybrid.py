@@ -3,7 +3,7 @@ every `shared_attn_every` SSM layers (arXiv:2411.15242).
 
 The shared block's *parameters* are reused at every application site, but each
 site keeps its own KV cache (different depths see different activations).
-long_500k decode: SSM state is O(1); the shared-attention sites keep
+500k-token decode: SSM state is O(1); the shared-attention sites keep
 seq-length caches — chunk-sharded over 'model', so the per-chip footprint is
 (sites * 500k * d_kv / 16), which is what makes this arch long-context-serveable.
 """
@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from . import layers as L
-from .unroll_ctx import scan as uscan
 from . import mamba2 as M
 from .config import ArchConfig
 from .sharding import shard
@@ -81,7 +80,7 @@ def _segment_scan(params, x, cfg: ArchConfig, dtype, remat: bool):
     def seg_scan(x, blocks_slice):
         def sb(x, blk):
             return mamba_body(blk, x), None
-        x, _ = uscan(sb, x, blocks_slice)
+        x, _ = jax.lax.scan(sb, x, blocks_slice)
         return x
 
     take = lambda tree, lo, hi: jax.tree.map(lambda l: l[lo:hi], tree)
@@ -158,7 +157,7 @@ def _run_cached(params, x, caches: HybridCaches, cfg: ArchConfig, dtype,
             blk, cache = blk_cache
             xc, cache = M.mamba_block(blk, xc, cfg, dtype, cache)
             return xc, cache
-        x, new_caches = uscan(sb, x, (pslice, cslice))
+        x, new_caches = jax.lax.scan(sb, x, (pslice, cslice))
         return x, new_caches
 
     mam, att = caches.mamba, caches.attn

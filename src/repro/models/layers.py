@@ -5,8 +5,7 @@ Conventions:
   * activations compute in bf16 (configurable), params stored f32 (the ByzSGD
     server replicas do f32 SGD math; casts happen on entry).
   * attention is *blocked* (online-softmax over KV chunks) so 32k-prefill
-    never materialises an [S, S] score matrix — required for the dry-run
-    memory envelope and the production memory roofline.
+    never materialises an [S, S] score matrix in device memory.
   * decode KV caches are stored chunk-sharded: [B, kvH, n_chunks, chunk, hd]
     with n_chunks mapped to the 'model' mesh axis (flash-decode with
     log-sum-exp merge across chunks => works for any kv-head count, incl.
@@ -104,10 +103,9 @@ NEG = jnp.float32(-1e30)
 
 
 def _naive_attention(q, k, v, *, causal, window, cross):
-    """Reference/full attention. Identical FLOP count to the blocked path
-    (which also computes every masked block) but loop-free — used as the
-    dry-run cost-probe path (unroll_ctx) so cost_analysis sees all the work,
-    and as the test oracle."""
+    """Reference/full attention: materialises the [Sq, Skv] scores in one
+    piece. The oracle the blocked path and the flash kernels are tested
+    against."""
     B, Sq, H, hd = q.shape
     Skv, kvH = k.shape[1], k.shape[2]
     rep = H // kvH
@@ -183,21 +181,15 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     cross => no causal mask (whisper cross-attention / encoder).
     Never materialises more than [B, H, q_block, kv_block] scores.
     """
-    from .unroll_ctx import active as _unroll_active
-    if _unroll_active():
-        return _naive_attention(q, k, v, causal=causal, window=window,
-                                cross=cross)
     import os as _os
-    if ((jax.default_backend() == "tpu"
-         and _os.environ.get("REPRO_NO_FLASH") != "1")
+    if (jax.default_backend() == "tpu"
             or _os.environ.get("REPRO_FLASH") == "1"):
-        # production TPU path: fused Pallas flash attention, forward AND
-        # backward (VMEM-resident scores — removes the O(S^2) HBM traffic
-        # that dominates the memory roofline term; kernels/flash_attention
-        # pairs the kernels via custom_vjp, so the training hot path runs
-        # them too). REPRO_NO_FLASH=1 falls back to the blocked path;
-        # REPRO_FLASH=1 forces the kernels elsewhere (Pallas interpret mode
-        # off-TPU — the CI hot-path smoke).
+        # TPU path: fused Pallas flash attention, forward AND backward
+        # (VMEM-resident scores — removes the O(S^2) HBM traffic of the
+        # scores; kernels/flash_attention pairs the kernels via custom_vjp,
+        # so the training hot path runs them too). REPRO_FLASH=1 forces the
+        # kernels elsewhere (Pallas interpret mode off-TPU — the CI hot-path
+        # smoke).
         return _flash_attention(q, k, v, causal=causal and not cross,
                                 window=window, q_block=q_block,
                                 kv_block=kv_block)
@@ -431,7 +423,6 @@ def cross_entropy_chunked(hidden, table_params, labels, chunk: int = 512):
     ever materialising the [B,S,V] logits (remat per chunk). This is what
     keeps the train-step memory envelope vocab-independent."""
     from .sharding import shard as _shard
-    from .unroll_ctx import scan as _uscan
     B, S, D = hidden.shape
     table = table_params["table"]
     chunk = min(chunk, S)
@@ -452,11 +443,6 @@ def cross_entropy_chunked(hidden, table_params, labels, chunk: int = 512):
         lse = jax.nn.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
         return jnp.sum((lse - ll) * vc)
-
-    from .unroll_ctx import active as _unroll_active
-    if _unroll_active():  # cost-probe: loop-free, flop-identical
-        tot = jnp.sum(jax.vmap(chunk_nll)(hb, lb, vb))
-        return tot / (B * S)
 
     def body(acc, xs):
         return acc + chunk_nll(*xs), None

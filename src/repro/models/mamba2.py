@@ -9,7 +9,7 @@ K-dim blowup), the inter-chunk term a single state contraction. Per-chunk
 transients stay at tens of MB (DESIGN.md §Arch notes).
 
 Decode is the O(1)-state single-token recurrence — this is what makes
-long_500k serve_step sub-quadratic for zamba2 (and rwkv6, see rwkv6.py).
+500k-token decode sub-quadratic for zamba2 (and rwkv6, see rwkv6.py).
 """
 from __future__ import annotations
 
@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from . import layers as L
-from .unroll_ctx import scan as uscan
 from .config import ArchConfig
 from .sharding import shard
 
@@ -121,19 +120,10 @@ def ssd_chunked(xh, la, Bm, Cm, h0, chunk: int):
                  + jnp.einsum("bjn,bjhp,bjh->bhpn", Bc, u, wdec))
         return h_new, (y_inter + y_intra)
 
-    from .unroll_ctx import active as _unroll_active
-    if _unroll_active():
-        # COST-PROBE PATH: see rwkv6.wkv_chunked — flop-exact, value-wrong.
-        _, ys = jax.vmap(body, in_axes=(None, 0))(
-            h0.astype(jnp.float32),
-            (xh.astype(jnp.float32), la, Bm.astype(jnp.float32),
-             Cm.astype(jnp.float32)))
-        h_final = h0.astype(jnp.float32)
-    else:
-        h_final, ys = jax.lax.scan(body, h0.astype(jnp.float32),
-                                   (xh.astype(jnp.float32), la,
-                                    Bm.astype(jnp.float32),
-                                    Cm.astype(jnp.float32)))
+    h_final, ys = jax.lax.scan(body, h0.astype(jnp.float32),
+                               (xh.astype(jnp.float32), la,
+                                Bm.astype(jnp.float32),
+                                Cm.astype(jnp.float32)))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(Bsz, nch * chunk, H, P)
     return y[:, :S], h_final
 

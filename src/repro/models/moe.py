@@ -2,7 +2,7 @@
 
 Routing: token-choice top-k with per-expert capacity (C = ceil(T * top_k / E *
 capacity_factor)); tokens beyond capacity are dropped (standard practice, keeps
-compute static for the dry-run). Dispatch is index-gather based (no [T, E, C]
+compute static). Dispatch is index-gather based (no [T, E, C]
 one-hot tensors — at 1M tokens those are infeasible): for each expert we take
 the top-C tokens by router weight, process [E, C, D] with batched per-expert
 matmuls, and scatter-add back.
@@ -18,8 +18,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from .unroll_ctx import scan as uscan
 
 from . import layers as L
 from . import transformer as TF
@@ -51,17 +49,13 @@ def moe_ffn(p, x, cfg: ArchConfig, dtype):
     B, S, D = x.shape
     T = B * S
     if T > MOE_CHUNK_TOKENS:
-        from .unroll_ctx import active as _unroll_active
         nc = -(-T // MOE_CHUNK_TOKENS)
         while T % nc:
             nc += 1
         xt = x.reshape(nc, T // nc, 1, D)  # chunks as [b=Tc, s=1] pseudo-batch
-        if _unroll_active():  # cost-probe: loop-free, flop-identical
-            out = jax.vmap(lambda c: _moe_tokens(p, c, cfg, dtype))(xt)
-        else:
-            def body(_, c):
-                return None, _moe_tokens(p, c, cfg, dtype)
-            _, out = jax.lax.scan(body, None, xt)
+        def body(_, c):
+            return None, _moe_tokens(p, c, cfg, dtype)
+        _, out = jax.lax.scan(body, None, xt)
         return out.reshape(B, S, D)
     return _moe_tokens(p, x, cfg, dtype)
 
@@ -155,7 +149,7 @@ def forward(params, tokens, *, cfg: ArchConfig, remat: bool = True):
     def scan_body(x, blk):
         return body(blk, x), None
 
-    x, _ = uscan(scan_body, x, params["blocks"])
+    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     _, norm = TF._norm_fns(cfg)
     return norm(params["ln_f"], x)
 
@@ -192,7 +186,7 @@ def prefill(params, batch, caches, *, cfg: ArchConfig):
         x = x + shard(moe_ffn(blk["moe"], h, cfg, dtype), "act_btd")
         return x, cache
 
-    x, caches = uscan(scan_body, x, (params["blocks"], caches))
+    x, caches = jax.lax.scan(scan_body, x, (params["blocks"], caches))
     hidden = norm(params["ln_f"], x[:, -1:])
     lg = TF.logits_fn(params, hidden, cfg)
     return lg[:, 0], caches

@@ -7,7 +7,7 @@ Bundle methods (all pure, jit/vmap-able):
     decode(params, caches, batch) -> (logits, caches)
     init_caches(batch, max_len, n_chunks) -> caches
     make_batch(kind, B, S, key) -> concrete batch    (smoke tests / examples)
-    batch_specs(kind, B, S) -> dict of ShapeDtypeStruct (dry-run input_specs)
+    batch_specs(kind, B, S) -> dict of ShapeDtypeStruct (abstract inputs)
 """
 from __future__ import annotations
 
@@ -87,7 +87,7 @@ class ModelBundle:
                 "labels": jax.ShapeDtypeStruct((B, S), i32)}
 
     def batch_specs(self, kind: str, B: int, S: int) -> dict:
-        """ShapeDtypeStruct stand-ins for every model input (dry-run)."""
+        """ShapeDtypeStruct stand-ins for every model input."""
         cfg = self.cfg
         bf16, i32 = jnp.bfloat16, jnp.int32
         if kind == "train" or kind == "prefill":
@@ -121,20 +121,11 @@ class ModelBundle:
                 out[name] = (0.02 * jax.random.normal(k, sds.shape)).astype(sds.dtype)
         return out
 
-    # -- shape-cell helpers ----------------------------------------------------
-    def supports_cell(self, shape_name: str) -> tuple[bool, str]:
-        """Spec-mandated skips: long_* needs sub-quadratic serve; encoder-only
-        (none here — whisper is enc-dec) would skip decode."""
-        if shape_name.startswith("long_") and not self.cfg.subquadratic:
-            return False, ("full quadratic attention: 500k-context serve_step "
-                           "skipped per assignment (see DESIGN.md)")
-        return True, ""
-
 
 def get_bundle(arch_id: str, reduced: bool = False, depth: int | None = None,
                **overrides) -> ModelBundle:
-    """depth: override n_layers only (dry-run cost probes — everything else
-    stays full-size; encoder depth scales with it for enc-dec archs).
+    """depth: override n_layers only (everything else stays full-size;
+    encoder depth scales with it for enc-dec archs).
     ``overrides`` replace config fields: on the reduced sibling when
     ``reduced``, else on the published config."""
     import dataclasses

@@ -22,7 +22,7 @@ pairwise decays inside each reduction that reads them, so no [C,C,K] tensor
 is kept for all chunks. Only the [B,H,K,V] state passes from chunk to
 chunk, in a scan whose step reads it for the chunk's inter-chunk output and
 adds the chunk's own k (x) v. Decode is the O(1)-state recurrence =>
-long_500k serve_step is sub-quadratic.
+500k-token decode is sub-quadratic.
 
 Departure from the published model: a log decay below ``LOG_DECAY_FLOOR``
 (-20) is taken as -20, i.e. a decay below e^-20 ~ 2e-9 is taken as e^-20.
@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import layers as L
-from .unroll_ctx import scan as uscan
 from .config import ArchConfig
 from .sharding import shard
 
@@ -210,7 +209,7 @@ def wkv_chunked(r, k, v, lw, u, s0, chunk: int = 16):
                 + jnp.einsum("bhck,bhcv->bhkv", kw_c, v_c),
                 jnp.einsum("bhck,bhkv->bhcv", q_c, s))
 
-    s_fin, y_inter = uscan(step, s0.astype(jnp.float32), (q, kw, vc, a))
+    s_fin, y_inter = jax.lax.scan(step, s0.astype(jnp.float32), (q, kw, vc, a))
     y = (y + y_inter).transpose(1, 0, 3, 2, 4).reshape(Bsz, nch * chunk, H, K)
     return y[:, :S], s_fin
 
@@ -327,7 +326,7 @@ def forward(params, tokens, *, cfg: ArchConfig, remat: bool = True):
     def scan_body(x, blk):
         return body(blk, x), None
 
-    x, _ = uscan(scan_body, x, params["blocks"])
+    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     return L.layernorm(params["ln_f"], x, cfg.norm_eps)
 
 
@@ -349,7 +348,7 @@ def _run_with_cache(params, x, caches, cfg: ArchConfig, dtype):
         x, cache = block(blk, x, cfg, dtype, cache)
         return x, cache
 
-    x, caches = uscan(scan_body, x, (params["blocks"], caches))
+    x, caches = jax.lax.scan(scan_body, x, (params["blocks"], caches))
     return L.layernorm(params["ln_f"], x, cfg.norm_eps), caches
 
 

@@ -15,7 +15,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .unroll_ctx import scan as uscan
 
 from . import layers as L
 from .config import ArchConfig
@@ -98,7 +97,7 @@ def forward(params, tokens=None, *, cfg: ArchConfig, embeds=None,
     def scan_body(x, blk):
         return body(blk, x), None
 
-    x, _ = uscan(scan_body, x, params["blocks"])
+    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     _, norm = _norm_fns(cfg)
     return norm(params["ln_f"], x)
 
@@ -161,7 +160,7 @@ def prefill(params, batch, caches, *, cfg: ArchConfig):
         x, cache = _block_prefill(blk, x, positions, cfg, dtype, cache)
         return x, cache
 
-    x, caches = uscan(scan_body, x, (params["blocks"], caches))
+    x, caches = jax.lax.scan(scan_body, x, (params["blocks"], caches))
     _, norm = _norm_fns(cfg)
     hidden = norm(params["ln_f"], x[:, -1:])
     lg = logits_fn(params, hidden, cfg)

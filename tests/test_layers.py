@@ -1,11 +1,12 @@
 """Layer-level oracles: blocked attention, flash decode, chunked scans,
-chunked cross-entropy."""
+chunked cross-entropy, token-chunked MoE dispatch."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.models import layers as L
+from repro.models import moe
 from repro.models.mamba2 import ssd_chunked
 from repro.models.rwkv6 import wkv_chunked
 
@@ -130,3 +131,21 @@ def test_mrope_matches_rope_for_equal_ids():
     a = L.apply_rope(x, pos, 1e4)
     b = L.apply_rope(x, pos3, 1e4)
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("S", [8, 9], ids=["chunk_divides", "chunks_raised"])
+def test_moe_token_chunks_match_one_dispatch(S, monkeypatch):
+    """Past ``MOE_CHUNK_TOKENS`` tokens, ``moe_ffn`` dispatches the tokens
+    in equal chunks through a scan. T = 16 takes 4 chunks of 4; T = 18
+    takes 6 of 3, since 5 does not divide it. With capacity for every token
+    (capacity_factor = E / top_k), the chunks give one dispatch's result."""
+    from repro.models.registry import get_config
+    cfg = get_config("dbrx-132b").reduced()
+    cfg = cfg.reduced(capacity_factor=cfg.n_experts / cfg.top_k)
+    p = moe.init_moe_ffn(k(80), cfg)
+    x = jax.random.normal(k(81), (2, S, cfg.d_model))
+    want = moe._moe_tokens(p, x, cfg, jnp.float32)
+    monkeypatch.setattr(moe, "MOE_CHUNK_TOKENS", 4)
+    fn = jax.jit(lambda p, x: moe.moe_ffn(p, x, cfg, jnp.float32))
+    assert "scan[" in str(jax.make_jaxpr(fn)(p, x))
+    np.testing.assert_allclose(fn(p, x), want, rtol=1e-5, atol=1e-5)

@@ -229,3 +229,31 @@ class TestEngineMechanics:
             2 * (G - 1) * P * 4
         assert protocol.collective_volume_bytes(sharded, P) == \
             protocol.collective_volume_bytes(naive, P)
+
+
+@pytest.mark.parametrize("body", [(6, 24, 128), (1024, 64)],
+                         ids=["layer_stack", "wide_table"])
+def test_aggregate_gradients_streamed_equals_whole(body):
+    """A leaf larger than ``chunk_bytes`` is aggregated in a loop: a
+    [G, L, ...] layer stack one layer at a time (``_map_dim1``), a wide
+    [G, V, D] table (V past the layer-stack limit) in slices of D
+    (``_map_last_chunks``). The loop gives the whole leaf's weighted sums."""
+    rng = np.random.default_rng(0)
+    grads = {"w": rng.standard_normal((G,) + body).astype(np.float32)}
+    weights = rng.dirichlet(np.ones(G), G).astype(np.float32)
+
+    def aggregate(chunk_bytes):
+        pcfg = protocol.ProtocolConfig(
+            n_groups=G, f_workers=1, f_servers=1, q_workers=4, q_servers=4,
+            chunk_bytes=chunk_bytes)
+        fn = jax.jit(lambda g, w: protocol.aggregate_gradients(g, w, pcfg))
+        jaxpr = str(jax.make_jaxpr(fn)(grads, weights))
+        loops = jaxpr.count("scan[") + jaxpr.count("while[")
+        return np.asarray(fn(grads, weights)["w"]), loops
+
+    streamed, n_streamed = aggregate(4096)
+    whole, n_whole = aggregate(2**40)
+    assert (n_streamed, n_whole) == (1, 0)
+    want = np.einsum("sw,w...->s...", weights.astype(np.float64), grads["w"])
+    np.testing.assert_allclose(streamed, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(streamed, whole, rtol=1e-6, atol=1e-6)
